@@ -679,7 +679,7 @@ def main(argv=None):
                    help="run a subset of the benchmark sections")
     p.add_argument("--mesh", type=int, default=None,
                    help="shard count of the jax+shard legs (default: every "
-                        "visible device; clamped with a warning)")
+                        "visible device; at most the visible count)")
     p.add_argument("--mesh2d", default=None, metavar="NxM",
                    help="scenario x policy-group grid of the jax+shard2d "
                         "and jax+refine+shard legs, e.g. 4x2 (default: "

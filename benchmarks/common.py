@@ -9,30 +9,26 @@ choices (price law, early starts).
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import numpy as np
 
 from repro.core import generate_chain_jobs, sweep_policies
 from repro.core.scheduler import Policy
-from repro.engine import ScenarioSpec, as_source, make_scenarios
+from repro.engine import (
+    ScenarioSpec,
+    as_source,
+    make_scenarios,
+    setup_persistent_cache,
+)
 
 __all__ = ["Setup", "make_setup", "sweep_min", "greedy_min",
            "argparser", "print_table"]
 
-# Reuse XLA executables across benchmark PROCESSES (DESIGN.md §11): point
-# jax's persistent compilation cache at a local directory so repeated
-# paper-table runs skip recompilation entirely. Opt out (e.g. when timing
-# cold compiles, as bench_pipeline does by not importing this module)
-# with REPRO_JAX_CACHE_DIR=0.
-if os.environ.get("REPRO_JAX_CACHE_DIR") != "0":
-    try:
-        from repro.engine import setup_persistent_cache
-
-        setup_persistent_cache()
-    except Exception:
-        pass  # jax absent or too old: benchmarks still run, just colder
+# Reuse XLA executables across benchmark PROCESSES (DESIGN.md §11) so
+# repeated paper-table runs skip recompilation. bench_pipeline times cold
+# compiles and therefore does not import this module.
+setup_persistent_cache()
 
 
 class Setup:
@@ -77,8 +73,8 @@ def make_setup(n_jobs: int, job_type: int, seed: int = 0,
     on device for the jax/pallas backends, S bounded by wall clock rather
     than host memory (``adaptive`` requires this path: it needs the
     stream's chunk-boundary feedback). ``mesh`` (an int shard count from
-    ``--mesh``, clamped to visible devices with a warning) shards the
-    scenario axis across a device mesh (DESIGN.md §9; jax backend only).
+    ``--mesh``, at most the visible devices) shards the scenario axis
+    across a device mesh (DESIGN.md §9; jax backend only).
     """
     jobs = generate_chain_jobs(n_jobs, job_type, seed=seed)
     horizon = max(j.deadline for j in jobs) + 1.0
@@ -152,8 +148,8 @@ def argparser(desc: str) -> argparse.ArgumentParser:
                    help="evaluation-engine backend")
     p.add_argument("--mesh", type=int, default=None,
                    help="shard the scenario axis over an N-way device mesh "
-                        "(jax backend; clamped to visible devices with a "
-                        "warning — force N CPU devices with XLA_FLAGS="
+                        "(jax backend; N may not exceed the visible devices "
+                        "— force N CPU devices with XLA_FLAGS="
                         "--xla_force_host_platform_device_count=N)")
     return p
 

@@ -36,7 +36,7 @@ saves one Chrome/Perfetto trace JSON covering every selected section
 Every exp accepts --scenarios S / --scenario-kind / --backend to evaluate S
 spot-market scenarios in one engine pass (S=1 = the paper's tables), and
 --mesh N to shard the scenario axis over an N-way device mesh (jax
-backend; clamped to the visible device count).
+backend; N may not exceed the visible device count).
 """
 
 from __future__ import annotations

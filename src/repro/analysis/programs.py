@@ -388,15 +388,15 @@ def _build_kernel_programs() -> list[ProgramSpec]:
                   _sds((R, L), jnp.float32))
     out.append(ProgramSpec("kernels.policy_cost.chain", chain, chain_args,
                            dict(_ZERO)))
-    # hedge_replay's traceable core on its padded layout (S=2, K=1).
-    J, P, BJ = 3, 4, 8
-    Jp, Pp = 8, 128
-    n_rows = 8
+    # hedge_replay's traceable core on its padded layout (S=2, K=1): one
+    # 128-job block, one 512-row trajectory ring, 4 policies in 128 lanes.
+    P, BJ, W = 4, 128, 512
+    Jp, Pp = 128, 128
     hedge = jax.jit(functools.partial(
-        _hedge_call, K=1, J=J, n_rows=n_rows, Pp=Pp, m=P, BJ=BJ,
-        interpret=True))
-    hedge_args = (_sds((2, Jp, Pp), jnp.float32), _sds((1, Jp), jnp.float32),
-                  _sds((2, Jp), jnp.float32), _sds((1, Jp), jnp.int32))
+        _hedge_call, K=1, W=W, Pp=Pp, m=P, BJ=BJ, interpret=True))
+    hedge_args = (_sds((2, Jp, Pp), jnp.float32),
+                  _sds((1, 1, Jp), jnp.float32),
+                  _sds((2, 1, Jp), jnp.float32), _sds((1, Jp), jnp.int32))
     out.append(ProgramSpec("kernels.hedge_replay", hedge, hedge_args,
                            dict(_ZERO)))
     # flash attention fwd: 2 heads, Sq=Sk=8, dh=8, one block.

@@ -29,7 +29,6 @@ Implementation notes (faithful, but vectorized):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import numpy as np
 
@@ -193,29 +192,6 @@ def run_tola(
                       fixed_unit_costs=fixed, learn=lr)
 
 
-def _round_mesh(mesh, avails):
-    """The mesh an evaluation round actually gets to use.
-
-    Since the 2-D GridMesh landed, refinement rounds (per-scenario
-    ``avails``) shard like round 0 does. The one remaining fallback —
-    ``engine.backend_jax.SHARDED_PS`` switched off — is NEVER silent: the
-    round drops to unsharded evaluation with a ``UserWarning`` naming the
-    reason, so a sweep cannot quietly lose its device mesh mid-run.
-    """
-    if mesh is None or avails is None:
-        return mesh
-    from repro.engine import backend_jax
-
-    if getattr(backend_jax, "SHARDED_PS", False):
-        return mesh
-    warnings.warn(
-        "run_tola_scenarios: dropping mesh= for this refinement round — "
-        "the sharded per-scenario availability path is disabled "
-        "(engine.backend_jax.SHARDED_PS is False); evaluating unsharded",
-        UserWarning, stacklevel=3)
-    return None
-
-
 def run_tola_scenarios(
     jobs: list[ChainJob],
     policies: list[Policy],
@@ -246,10 +222,7 @@ def run_tola_scenarios(
     refinement rounds shard the per-scenario-availability pass — the
     (S, R, L) refined plan stacks ride the ``"data"`` axis next to the
     views, group rows the ``"model"`` axis, with zero collectives in the
-    eval hot loop. If the sharded per-scenario path is ever disabled
-    (``engine.backend_jax.SHARDED_PS`` False), the refinement rounds fall
-    back to unsharded evaluation WITH a ``UserWarning`` naming the reason
-    — never silently.
+    eval hot loop.
     """
     from repro.engine import evaluate_grid
     from repro.learn import as_spec
@@ -267,8 +240,7 @@ def run_tola_scenarios(
         res = evaluate_grid(
             jobs, policies, markets, r_total, windows=windows,
             selfowned=selfowned, early_start=early_start, pool="dedicated",
-            availability=avails, backend=backend,
-            mesh=_round_mesh(mesh, avails))
+            availability=avails, backend=backend, mesh=mesh)
         C = res.unit_cost
         rounds = [
             _tola_round(jobs, policies, C[s], arrivals, d, Z, spec, rngs[s],

@@ -94,15 +94,11 @@ def resolve_backend(backend: str) -> str:
                 f"override; pick from {_BACKENDS + ('auto',)}")
         backend = env
     if backend == "auto":
-        avail = available_backends()
-        try:
-            import jax
-            on_accel = jax.default_backend() != "cpu"
-        except Exception:
-            return "numpy"
-        if on_accel:
-            return "pallas" if "pallas" in avail else "jax"
-        return "numpy"
+        # No fallback: a jax that cannot import or find its platform is an
+        # error here, not a silent switch to the host oracle.
+        import jax
+
+        return "pallas" if jax.default_backend() != "cpu" else "numpy"
     if backend not in _BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; pick from "
                          f"{_BACKENDS + ('auto',)}")
@@ -401,8 +397,8 @@ def evaluate_grid(
     forces/forbids pallas interpret mode (default: interpret off-TPU).
 
     ``mesh`` shards the SCENARIO axis across a device mesh (DESIGN.md §9):
-    pass a ``ScenarioMesh``, an int shard count (clamped to available
-    devices with a warning), or a jax ``Mesh`` with a ``"data"`` axis.
+    pass a ``ScenarioMesh``, an int shard count (at most the visible
+    devices), or a jax ``Mesh`` with a ``"data"`` axis.
     Mesh evaluation is a jax-backend feature ("auto" resolves to jax;
     numpy/pallas raise) — each shard synthesizes and scores only its own
     scenario slice, with no cross-device traffic in the compiled programs;

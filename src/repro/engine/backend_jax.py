@@ -59,13 +59,7 @@ from repro.engine.plan import concat_rows, scenario_cat
 from repro.kernels.ref import chain_costs_ref, policy_cost_ref
 from repro.obs import record_jit, span
 
-__all__ = ["run", "SHARDED_PS"]
-
-# Per-scenario (refined) plans evaluate sharded since the 2-D mesh landed.
-# ``core/tola.py`` probes this flag before threading ``mesh=`` into a
-# refinement round and falls back (with a UserWarning) when it is False —
-# the escape hatch if a jax regression ever forces the ps shard path off.
-SHARDED_PS = True
+__all__ = ["run"]
 
 
 def _chain_body(A, C, arrival, ends, z_t, d_eff, pins, p_od, slot):
@@ -121,13 +115,11 @@ def _sharded_fns(mesh):
     scenario-only placement. Cached per mesh so repeated calls reuse the
     compiled program exactly like the unsharded module-scope jits.
     """
-    from jax.experimental.shard_map import shard_map
-
     dp = mesh.spec("scenario")            # P("data")
     gp = mesh.spec("group")               # P("model"); P(None) on 1-D mesh
     dgp = mesh.spec("scenario", "group")  # P("data", "model")
     rp = mesh.spec()                      # empty P(): replicated, any rank
-    sm = functools.partial(shard_map, mesh=mesh.mesh)
+    sm = functools.partial(jax.shard_map, mesh=mesh.mesh)
     chain = jax.jit(sm(
         _chain_body,
         in_specs=(dp, dp, gp, gp, gp, gp, gp, rp, rp), out_specs=dgp))

@@ -9,16 +9,35 @@ start grids (early_start=False) use the original per-task ``policy_cost``
 kernel on the flattened task batch.
 
 Off-TPU the kernels run in interpret mode (slow, parity-testing only);
-``interpret`` can be forced either way.
+``interpret`` can be forced either way. Each launch, wrapper layout work
+included, is one jitted program announced to ``repro.obs`` as
+``engine.eval.pallas_chain`` / ``engine.eval.pallas_task``; on a TPU its
+compiled text holds the Mosaic kernel as a ``tpu_custom_call``.
 """
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from repro.engine.plan import concat_rows, scenario_cat
+from repro.obs import record_jit
 
 __all__ = ["run"]
+
+
+@functools.lru_cache(maxsize=1)
+def _kernels():
+    """(chain, task) kernel launches, each jitted once per process."""
+    import jax
+
+    from repro.kernels.policy_cost import policy_cost, policy_cost_chain
+
+    return (jax.jit(policy_cost_chain, static_argnames=(
+                "slot", "p_od", "block_rows", "interpret")),
+            jax.jit(policy_cost, static_argnames=(
+                "slot", "p_od", "block_tasks", "interpret")))
 
 
 def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
@@ -26,8 +45,7 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.policy_cost import policy_cost, policy_cost_chain
-
+    chain, task = _kernels()
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     slot = batch.slot
@@ -107,9 +125,11 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
                 z_t[sl] = cat("z_t")
                 d_eff[sl] = cat("d_eff")
                 pins[sl] = cat("pins")
-        res = policy_cost_chain(
-            A, C, arrival, ends, z_t, d_eff, pins, slot=slot, p_od=p_od,
-            block_rows=block_rows, interpret=interpret)
+        args = (A, C, arrival, ends, z_t, d_eff, pins)
+        kw = dict(slot=slot, p_od=p_od, block_rows=block_rows,
+                  interpret=interpret)
+        record_jit("engine.eval.pallas_chain", chain, *args, **kw)
+        res = chain(*args, **kw)
         for key in ("spot_cost", "ondemand_cost", "spot_work",
                     "ondemand_work"):
             vals = np.asarray(res[key], np.float64)     # (B, S, R_max)
@@ -136,11 +156,12 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
             z_t = z_all[s] if gplan.per_scenario else z_one
             d_eff = d_all[s] if gplan.per_scenario else d_one
             flat = lambda a: jnp.asarray(a.reshape(R * L), jnp.float32)
-            r = policy_cost(
-                jnp.asarray(A[s], jnp.float32),
-                jnp.asarray(C[s], jnp.float32),
-                flat(starts), flat(ends), flat(z_t), flat(d_eff),
-                slot=slot, p_od=p_od, interpret=interpret)
+            args = (jnp.asarray(A[s], jnp.float32),
+                    jnp.asarray(C[s], jnp.float32),
+                    flat(starts), flat(ends), flat(z_t), flat(d_eff))
+            kw = dict(slot=slot, p_od=p_od, interpret=interpret)
+            record_jit("engine.eval.pallas_task", task, *args, **kw)
+            r = task(*args, **kw)
             r["ondemand_work"] = (
                 r["ondemand_cost"] / p_od if p_od > 0
                 else jnp.maximum(flat(z_t) - r["spot_work"], 0.0)

@@ -400,30 +400,28 @@ def evaluate_grid_delta(prev, jobs, policies, scenarios, r_total: int = 0, *,
 # Persistent (warm-disk) XLA compilation cache.
 # --------------------------------------------------------------------------
 
-def setup_persistent_cache(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` and enable it.
+# One fixed directory inside the checkout: the cache key includes the
+# directory, so a path that moves between runs never hits.
+_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), os.pardir, os.pardir, os.pardir, ".jax_cache"))
 
-    Resolution order: explicit argument, ``REPRO_JAX_CACHE_DIR``, then
-    ``~/.cache/repro-jax``. Thresholds are lowered so even the small CPU
-    programs of the test grids persist. Best-effort by design: returns the
-    cache directory on success and None when jax is missing or too old —
-    a numpy-only environment must not crash on import of its launcher.
+
+def setup_persistent_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and no
+    other directory is set here; otherwise the cache lives at
+    ``.jax_cache`` in the checkout. Thresholds are lowered so even the
+    small programs of the test grids persist.
     """
-    path = path or os.environ.get("REPRO_JAX_CACHE_DIR") \
-        or os.path.join(os.path.expanduser("~"), ".cache", "repro-jax")
-    try:
-        import jax
+    import jax
 
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_enable_compilation_cache", True)
-    except Exception:
-        return None
-    # Persist-everything thresholds (absent on some jax versions).
-    for key, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(key, val)
-        except Exception:
-            pass
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path

@@ -34,7 +34,6 @@ environments without it (the numpy oracle path).
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any
 
 import numpy as np
@@ -44,11 +43,6 @@ __all__ = [
 ]
 
 _OVERRIDES = {"scenario": "data", "group": "model", "bid": None}
-
-# Once-per-process clamp-warning keys: (requested data, requested model,
-# visible devices).  A sweep that builds the same over-subscribed mesh in
-# every cell warns exactly once per distinct request shape.
-_CLAMP_WARNED: set[tuple[int, int, int]] = set()
 
 
 def pad_to(k: int, n: int) -> int:
@@ -88,14 +82,12 @@ class GridMesh:
     @classmethod
     def create(cls, n_devices: int | None = None,
                model_devices: int = 1) -> "GridMesh":
-        """Mesh of ``n_devices x model_devices``, clamped to what exists.
+        """Mesh of ``n_devices x model_devices`` visible devices.
 
         ``n_devices`` (default: all remaining after the model axis) shards
         the scenario axis as ``"data"``; ``model_devices`` shards the
-        eval-group axis as ``"model"``.  Clamping warns (once per process
-        per request shape) rather than raises so ``--mesh 8`` scripts run
-        unchanged on a 1-device box (the 1x1 mesh is bit-identical to the
-        unsharded path).
+        eval-group axis as ``"model"``.  Asking for more devices than are
+        visible raises ``ValueError`` naming both counts.
         """
         import jax
 
@@ -111,17 +103,11 @@ class GridMesh:
         if n < 1:
             raise ValueError(f"mesh needs >= 1 device (got {n_devices})")
         if n * m > avail:
-            key = (n, m, avail)
-            if key not in _CLAMP_WARNED:
-                _CLAMP_WARNED.add(key)
-                warnings.warn(
-                    f"requested a {n}x{m} ({n * m}-device) scenario x group "
-                    f"mesh but only {avail} device(s) are visible — "
-                    f"clamping to {avail} (set "
-                    f"XLA_FLAGS=--xla_force_host_platform_device_count=N to "
-                    f"fake N host devices on CPU)", stacklevel=2)
-            m = min(m, avail)
-            n = max(avail // m, 1)
+            raise ValueError(
+                f"requested a {n}x{m} ({n * m}-device) scenario x group mesh "
+                f"but only {avail} device(s) are visible (on CPU, "
+                f"XLA_FLAGS=--xla_force_host_platform_device_count=N fakes "
+                f"N host devices)")
         shape, axes = ((n, m), ("data", "model")) if m > 1 else \
             ((n,), ("data",))
         mesh = make_mesh(shape, axes)
@@ -185,7 +171,7 @@ def as_scenario_mesh(mesh) -> GridMesh | None:
     """Normalize every accepted ``mesh=`` argument.
 
     Accepts ``None`` (unsharded), a ``GridMesh``/``ScenarioMesh``, an int
-    (scenario-shard count, clamped to available devices), or a raw jax
+    (scenario-shard count, at most the visible devices), or a raw jax
     ``Mesh`` whose axes include ``"data"`` (a ``"model"`` axis, when
     present, shards the eval-group axis).
     """

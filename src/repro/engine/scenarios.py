@@ -415,11 +415,9 @@ def _device_synth_fn(spec: ScenarioSpec, mesh=None):
 
     if mesh is None:
         return jax.jit(gen)
-    from jax.experimental.shard_map import shard_map
-
     dp = mesh.spec("scenario")
-    return jax.jit(shard_map(gen, mesh=mesh.mesh,
-                             in_specs=(dp, dp, dp, dp), out_specs=dp))
+    return jax.jit(jax.shard_map(gen, mesh=mesh.mesh,
+                                 in_specs=(dp, dp, dp, dp), out_specs=dp))
 
 
 @functools.lru_cache(maxsize=32)   # bounded: one entry per (slot, mesh)
@@ -454,13 +452,11 @@ def _device_views_fn(slot: float, mesh=None):
 
     if mesh is None:
         return jax.jit(views)
-    from jax.experimental.shard_map import shard_map
-
     dp = mesh.spec("scenario")
     rp = mesh.spec()   # empty P(): replicated, valid for rank-0 scalars
-    return jax.jit(shard_map(views, mesh=mesh.mesh,
-                             in_specs=(dp, dp, dp, dp, rp),
-                             out_specs=dp))
+    return jax.jit(jax.shard_map(views, mesh=mesh.mesh,
+                                 in_specs=(dp, dp, dp, dp, rp),
+                                 out_specs=dp))
 
 
 # --------------------------------------------------------------------------

@@ -14,7 +14,26 @@ import numpy as np
 from repro.core.simulate import FLEX_ABS, FLEX_REL
 
 __all__ = ["attention_ref", "ssd_ref", "policy_cost_ref", "chain_costs_ref",
-           "hedge_replay_ref"]
+           "hedge_replay_ref", "varying_like"]
+
+
+def varying_like(tree, *operands):
+    """Mark every leaf of ``tree`` as varying over each manual mesh axis
+    that any of ``operands`` varies over (a no-op outside ``shard_map``).
+
+    A ``lax.scan`` carry initialised from constants starts out unvarying,
+    but its body mixes in sharded operands; ``shard_map``'s type check
+    needs the carry's input and output types to be equal.
+    """
+    axes = set()
+    for o in operands:
+        axes |= set(jax.typeof(o).vma)
+
+    def cast(x):
+        missing = tuple(sorted(axes - set(jax.typeof(x).vma)))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree_util.tree_map(cast, tree)
 
 
 def hedge_replay_ref(C, etas, u, n_done):
@@ -187,6 +206,7 @@ def chain_costs_ref(A_cum, C_cum, arrival, ends, z_t, d_eff, pins,
     Returns per-row aggregates (spot/on-demand cost and work) plus the
     realized chain ``finish``.
     """
+    A_cum, C_cum = jnp.asarray(A_cum), jnp.asarray(C_cum)
     xs = (jnp.moveaxis(jnp.asarray(ends), 1, 0),
           jnp.moveaxis(jnp.asarray(z_t), 1, 0),
           jnp.moveaxis(jnp.asarray(d_eff), 1, 0),
@@ -206,8 +226,10 @@ def chain_costs_ref(A_cum, C_cum, arrival, ends, z_t, d_eff, pins,
         return (cur, sc + sim["spot_cost"], oc + sim["ondemand_cost"],
                 sw + sim["spot_work"], ow + sim["ondemand_work"]), None
 
-    zeros = jnp.zeros_like(jnp.asarray(arrival, jnp.result_type(ends)))
-    init = (jnp.asarray(arrival, zeros.dtype), zeros, zeros, zeros, zeros)
+    arrival = jnp.asarray(arrival, jnp.result_type(ends))
+    zeros = jnp.zeros_like(arrival)
+    init = varying_like((arrival, zeros, zeros, zeros, zeros),
+                        A_cum, C_cum, arrival, *xs)
     (cur, sc, oc, sw, ow), _ = jax.lax.scan(step, init, xs)
     return {"spot_cost": sc, "ondemand_cost": oc, "spot_work": sw,
             "ondemand_work": ow, "finish": cur}

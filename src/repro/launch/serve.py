@@ -12,7 +12,6 @@ lowers for the ``decode_*`` cells.
 from __future__ import annotations
 
 import argparse
-import os
 
 import jax
 import jax.numpy as jnp
@@ -86,15 +85,10 @@ def main(argv=None):
     args = p.parse_args(argv)
     # Serving restarts should not re-pay prefill/decode compiles: hook up
     # jax's persistent compilation cache (DESIGN.md §11) before any jit.
-    if os.environ.get("REPRO_JAX_CACHE_DIR") != "0":
-        try:
-            from repro.engine.cache import setup_persistent_cache
+    from repro.engine.cache import setup_persistent_cache
 
-            cache_dir = setup_persistent_cache()
-            if cache_dir:
-                print(f"[serve] persistent compilation cache: {cache_dir}")
-        except Exception:
-            pass
+    print(f"[serve] persistent compilation cache: "
+          f"{setup_persistent_cache()}")
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab, (args.requests, args.prompt_len),

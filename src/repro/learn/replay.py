@@ -130,6 +130,8 @@ def _scan_one(kind: str, ring: int):
     import jax
     import jax.numpy as jnp
 
+    from repro.kernels.ref import varying_like
+
     def one(C2, u1, eta1, gamma1, ev_kind, ev_j):
         m = C2.shape[-1]
 
@@ -152,8 +154,9 @@ def _scan_one(kind: str, ring: int):
                 lambda a, b: jnp.where(is_sample, a, b), st, new)
             return (st, rb_c, rb_p), (rb_c[slot], rb_p[slot], p @ c_row)
 
-        carry0 = (init_state(m, jnp), jnp.zeros(ring, jnp.int32),
-                  jnp.zeros(ring))
+        carry0 = varying_like(
+            (init_state(m, jnp), jnp.zeros(ring, jnp.int32),
+             jnp.zeros(ring)), C2, u1, eta1, gamma1, ev_kind, ev_j)
         (st, _, _), ys = jax.lax.scan(step, carry0, (ev_kind, ev_j))
         weights = sample_probs(kind, st, gamma1[-1], jnp)
         return ys[0], ys[1], ys[2], weights
@@ -233,7 +236,6 @@ def _sharded_fold(smesh, kinds_sig: tuple, ring: int, k0_pos: int):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     def fold(acc, C, u, valid, etas, gammas, ev_kind, ev_j, sample_pos, Z):
         parts = []
@@ -286,13 +288,10 @@ def _sharded_fold(smesh, kinds_sig: tuple, ring: int, k0_pos: int):
 
     dp = smesh.spec("scenario")
     rp = smesh.spec()
-    # check_rep=False: shard_map's replication checker can't see through
-    # the lax.scan carry (state touches the sharded C rows) and rejects an
-    # otherwise-valid program; the specs above are the contract.
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fold, mesh=smesh.mesh,
         in_specs=(rp, dp, dp, dp, rp, rp, rp, rp, rp, rp),
-        out_specs=(rp, dp), check_rep=False),
+        out_specs=(rp, dp)),
         donate_argnums=(0,))
 
 

@@ -25,7 +25,8 @@ Keys in use (see DESIGN.md Section 10): ``plan.device.full``,
 ``engine.eval.chain[:sharded]``, ``engine.eval.task[:sharded]``,
 ``engine.eval.chain_ps[:sharded]``, ``engine.eval.task_ps[:sharded]``
 (the per-scenario-availability refinement programs, sharded over both
-axes of a 2-D ``GridMesh``), ``learn.scan:<kind>``,
+axes of a 2-D ``GridMesh``), ``engine.eval.pallas_chain``,
+``engine.eval.pallas_task``, ``learn.scan:<kind>``,
 ``learn.fold:sharded``.
 """
 from __future__ import annotations
@@ -73,8 +74,10 @@ def hlo_metrics(fn, *args, **kwargs):
     """Lower+compile a jitted ``fn`` on example args and analyze the HLO.
 
     Returns ``{"flops", "bytes", "collective_bytes", "collective_counts",
-    "warnings"}``.  This is the programmatic face of the shard tests'
-    "grep the compiled text" assertions.
+    "tpu_custom_calls", "warnings"}`` — ``tpu_custom_calls`` counts the
+    Mosaic (Pallas TPU) kernels in the program, 0 for interpret mode.
+    This is the programmatic face of the shard tests' "grep the compiled
+    text" assertions.
     """
     from repro.launch.hlo_analysis import analyze
 
@@ -85,6 +88,7 @@ def hlo_metrics(fn, *args, **kwargs):
         "bytes": a["bytes"],
         "collective_bytes": a["collectives"],
         "collective_counts": collective_counts(txt),
+        "tpu_custom_calls": txt.count('custom_call_target="tpu_custom_call"'),
         "warnings": a["warnings"],
     }
 

@@ -325,14 +325,13 @@ def test_broken_placement_contract_fails_with_program_key():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.analysis.programs import verify_program
 
     devs = np.array(jax.devices())
     mesh = Mesh(devs, ("s",))
-    broken = jax.jit(shard_map(
+    broken = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(x, "s"), mesh=mesh,
         in_specs=P("s"), out_specs=P()))
     arg = jax.ShapeDtypeStruct((len(devs), 4), jnp.float32)

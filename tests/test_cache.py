@@ -289,3 +289,46 @@ def test_scenario_fingerprint_kinds():
     from repro.engine import ScenarioSpec
     spec = ScenarioSpec("fresh", 100.0, 4, seed=1)
     assert cache.scenario_fingerprint(spec) == spec
+
+
+# --------------------------------------------------------------------------
+# Persistent compilation cache placement (fresh processes: jax reads its
+# cache settings once per process)
+# --------------------------------------------------------------------------
+
+_CACHE_PROBE = r"""
+import json
+import jax
+import jax.numpy as jnp
+from repro.engine import setup_persistent_cache
+path = setup_persistent_cache()
+jax.jit(lambda x: x * 2 + 1)(jnp.arange(3.0)).block_until_ready()
+print(json.dumps({"path": path,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_persistent_cache_directory(tmp_path, env_set):
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    env = {"PYTHONPATH": str(repo / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": os.environ.get("PATH", ""),
+           "HOME": os.environ.get("HOME", "")}
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         cwd=tmp_path, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    want = str(tmp_path) if env_set else str(repo / ".jax_cache")
+    assert got["path"] == got["config"] == want
+    if env_set:
+        # the compiled program landed in the caller's directory
+        assert any(tmp_path.iterdir())

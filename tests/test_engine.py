@@ -162,6 +162,23 @@ def test_backend_resolution():
         resolve_backend("cuda")
 
 
+def test_auto_backend_surfaces_jax_platform_errors(monkeypatch):
+    """"auto" resolves by the platform jax reports; a jax that cannot
+    initialise its platform is an error, never a silent switch to numpy."""
+    jax = pytest.importorskip("jax")
+
+    def broken():
+        raise RuntimeError("no platform could be initialised")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="no platform"):
+        resolve_backend("auto")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_backend("auto") == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert resolve_backend("auto") == "numpy"
+
+
 def test_scenarios_must_share_grid():
     jobs, m = _setup()
     bad = SpotMarket(m.horizon + 50, seed=1)

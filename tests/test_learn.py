@@ -89,6 +89,26 @@ def test_pallas_kernel_parity_hedge():
     np.testing.assert_allclose(a.p_chosen, b.p_chosen, atol=TOL)
 
 
+def test_pallas_hedge_ring_wraps_on_long_stream():
+    """A stream much longer than its feedback delay keeps only a ring of
+    trajectory rows in VMEM; the wrapped ring must replay exactly like the
+    oracle."""
+    from repro.kernels.weight_update import ring_rows
+
+    rng = np.random.default_rng(3)
+    n, m = 1500, 7
+    C = rng.random((1, n, m))
+    arrivals = np.cumsum(rng.exponential(0.25, n))
+    d = 2.0
+    _, _, n_done = build_events(arrivals, d)
+    assert ring_rows(n_done, 128) < n   # the ring really wraps
+    a = replay(C, arrivals, d, learners=["hedge"], seed=4, backend="numpy")
+    b = replay(C, arrivals, d, learners=["hedge"], seed=4, backend="pallas")
+    np.testing.assert_array_equal(a.chosen, b.chosen)
+    np.testing.assert_allclose(a.weights, b.weights, atol=TOL)
+    np.testing.assert_allclose(a.p_chosen, b.p_chosen, atol=TOL)
+
+
 def test_hedge_replay_ref_matches_oracle():
     """kernels/ref.py's loop-free trajectory formulation == the sequential
     event loop (structurally different algorithms, same numbers)."""

@@ -162,6 +162,16 @@ def test_attr_coercion_json_safe():
 # EngineResult.timings as a span-derived view (bit-for-bit, numpy path)
 # --------------------------------------------------------------------------
 
+def _lsum(values) -> float:
+    """Left-to-right float sum, the order the engine and the tracer use
+    (Python >= 3.12's ``sum`` compensates, so it can differ in the last
+    ulp)."""
+    total = 0.0
+    for v in values:
+        total += v
+    return total
+
+
 def test_timings_match_span_totals_bitforbit():
     tr, res = _traced_numpy_run(S=6, chunk=2)
     tot = tr.totals()
@@ -178,8 +188,8 @@ def test_timings_match_span_totals_bitforbit():
     for entry, ss, es in zip(chunks, synth_spans, eval_spans):
         assert entry["synth"] == ss.seconds
         assert entry["eval"] == es.seconds
-    assert sum(c["synth"] for c in chunks) == res.timings["synth"]
-    assert sum(c["eval"] for c in chunks) == res.timings["eval"]
+    assert _lsum(c["synth"] for c in chunks) == res.timings["synth"]
+    assert _lsum(c["eval"] for c in chunks) == res.timings["eval"]
     # every chunk span parents exactly one synth + one eval span
     for c in tr.named("chunk"):
         kids = tr.children(c.id)
@@ -252,8 +262,8 @@ def test_overlap_synth_contract():
     for res in (base, ov):
         chunks = res.timings["chunks"]
         assert len(chunks) == 4
-        assert sum(c["synth"] for c in chunks) == res.timings["synth"]
-        assert sum(c["eval"] for c in chunks) == res.timings["eval"]
+        assert _lsum(c["synth"] for c in chunks) == res.timings["synth"]
+        assert _lsum(c["eval"] for c in chunks) == res.timings["eval"]
     np.testing.assert_allclose(ov.unit_cost, base.unit_cost, rtol=0, atol=0)
 
 

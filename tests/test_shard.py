@@ -13,9 +13,10 @@ before jax initializes.
 """
 
 import json
+import os
 import subprocess
 import sys
-import warnings
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -102,42 +103,23 @@ def test_mesh_2d_create(shape):
     assert (got.data_shards, got.model_shards) == shape
 
 
-def _clear_clamp_dedupe():
-    from repro.engine import mesh as mesh_mod
-
-    mesh_mod._CLAMP_WARNED.clear()
-
-
-def test_mesh_create_clamps_with_warning():
-    _clear_clamp_dedupe()
+def test_mesh_create_raises_past_visible_devices_1d():
     avail = len(jax.devices())
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        mesh = ScenarioMesh.create(avail + 7)
-    assert mesh.n_shards == avail
-    msgs = [str(x.message) for x in w]
-    assert any("clamping" in s for s in msgs)
-    assert any("xla_force_host_platform_device_count" in s for s in msgs)
+    with pytest.raises(ValueError) as err:
+        ScenarioMesh.create(avail + 7)
+    msg = str(err.value)
     # the message names both the requested and the visible device counts
-    assert any(str(avail + 7) in s and str(avail) in s for s in msgs)
+    assert f"{avail + 7}-device" in msg and f"only {avail} device" in msg
+    assert "xla_force_host_platform_device_count" in msg
 
 
-def test_mesh_clamp_warning_dedupes_per_process():
-    # A sweep building the same over-subscribed mesh in every cell warns
-    # exactly ONCE per distinct (requested, visible) key — not per call.
-    _clear_clamp_dedupe()
+def test_mesh_create_raises_past_visible_devices_2d():
     avail = len(jax.devices())
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        ScenarioMesh.create(avail + 7)
-        ScenarioMesh.create(avail + 7)
-        ScenarioMesh.create(avail + 7)
-    assert len([x for x in w if "clamping" in str(x.message)]) == 1
-    # a DIFFERENT over-subscription is a new key and warns again
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        ScenarioMesh.create(avail + 9)
-    assert len([x for x in w if "clamping" in str(x.message)]) == 1
+    with pytest.raises(ValueError) as err:
+        GridMesh.create(avail, 2)
+    msg = str(err.value)
+    assert f"{avail}x2 ({2 * avail}-device)" in msg
+    assert f"only {avail} device" in msg
 
 
 def test_as_scenario_mesh_normalization():
@@ -357,25 +339,28 @@ def test_run_tola_scenarios_accepts_mesh():
         assert np.array_equal(a.chosen, b.chosen)
 
 
-def test_run_tola_scenarios_mesh_fallback_warns(monkeypatch):
-    # Regression (PR 10 satellite): a dropped mesh is NEVER silent. With
-    # the sharded per-scenario path disabled, refinement rounds fall back
-    # to unsharded evaluation and say so.
+def test_run_tola_scenarios_keeps_mesh_every_round(monkeypatch):
+    # Every evaluate_grid call of a TOLA run, round 0 and each pool
+    # refinement round, receives the caller's mesh unchanged.
+    import repro.engine as engine
     from repro.core import run_tola_scenarios
-    from repro.engine import backend_jax
 
+    seen = []
+    real = engine.evaluate_grid
+
+    def spy(*args, **kwargs):
+        seen.append((kwargs.get("availability") is not None,
+                     kwargs.get("mesh")))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(engine, "evaluate_grid", spy)
     jobs, horizon = _setup(n=12)
     markets = make_scenarios(horizon, 2, seed=1)
-    ref = run_tola_scenarios(jobs, GRID, markets, r_total=300, seed=0,
-                             pool_iters=1, backend="jax")
-    monkeypatch.setattr(backend_jax, "SHARDED_PS", False)
-    with pytest.warns(UserWarning, match="dropping mesh=.*SHARDED_PS"):
-        got = run_tola_scenarios(jobs, GRID, markets, r_total=300, seed=0,
-                                 pool_iters=1, backend="jax",
-                                 mesh=ScenarioMesh.create(1))
-    # the fallback still computes the same answer, just unsharded
-    for a, b in zip(ref, got):
-        assert np.array_equal(a.cost_matrix, b.cost_matrix)
+    mesh = ScenarioMesh.create(1)
+    run_tola_scenarios(jobs, GRID, markets, r_total=300, seed=0,
+                       pool_iters=2, backend="jax", mesh=mesh)
+    assert [refined for refined, _ in seen] == [False, True, True]
+    assert all(m is mesh for _, m in seen)
 
 
 def test_sweep_policies_accepts_mesh():
@@ -596,9 +581,9 @@ print(json.dumps(out))
 def test_sharded_8_devices_subprocess():
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
-             "HOME": "/root"},
-        cwd="/root/repo", timeout=900)
+        env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+             "HOME": os.environ.get("HOME", "")},
+        cwd=Path(__file__).resolve().parents[1], timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["n_shards"] == 8
