@@ -41,6 +41,7 @@ from repro.core.scheduler import (
     build_plans,
 )
 from repro.core.types import ChainJob
+from repro.obs import span
 
 __all__ = ["TolaResult", "cost_matrix", "run_tola", "run_tola_scenarios"]
 
@@ -118,21 +119,28 @@ def _stream_meta(jobs: list[ChainJob]):
 
 
 def _tola_round(jobs, policies, C, arrivals, d, Z, spec, rng, market,
-                r_total, windows, selfowned, early_start):
+                r_total, windows, selfowned, early_start, round_=0,
+                scenario=0):
     """One Alg.-4 round for one scenario: replay the learner over C, run the
     sampled policies against the shared pool, return the realized residual-
     availability query for the next refinement."""
     from repro.learn import replay as learn_replay
 
-    lr = learn_replay(C, arrivals, d, workload=Z, learners=[spec],
-                      rng=rng, backend="numpy")
-    chosen = lr.chosen[0, 0]
-    plan = build_plans(jobs, [policies[c] for c in chosen], r_total, windows)
-    r_alloc, pool = _allocate_pool(plan, r_total, selfowned,
-                                   market.slots_per_unit)
-    realized = _simulate_plan(plan, r_alloc, market, early_start)
-    availability = None if pool is None else \
-        _residual_availability(pool, r_total, market.slot)
+    with span("tola.round", round=round_, scenario=scenario):
+        lr = learn_replay(C, arrivals, d, workload=Z, learners=[spec],
+                          rng=rng, backend="numpy")
+        chosen = lr.chosen[0, 0]
+        with span("tola.plans"):
+            plan = build_plans(jobs, [policies[c] for c in chosen], r_total,
+                               windows)
+        with span("tola.pool"):
+            r_alloc, pool = _allocate_pool(plan, r_total, selfowned,
+                                           market.slots_per_unit)
+        with span("tola.realize"):
+            realized = _simulate_plan(plan, r_alloc, market, early_start)
+        with span("tola.availability"):
+            availability = None if pool is None else \
+                _residual_availability(pool, r_total, market.slot)
     return lr, chosen, realized, availability
 
 
@@ -176,15 +184,16 @@ def run_tola(
 
     availability = None
     iters = 1 + (pool_iters if r_total > 0 else 0)
-    for it in range(iters):
-        if it == 0 and _C0 is not None:
-            C = _C0
-        else:
-            C = cost_matrix(jobs, policies, market, r_total, windows,
-                            selfowned, early_start, availability, backend)
-        lr, chosen, realized, availability = _tola_round(
-            jobs, policies, C, arrivals, d, Z, spec, rng, market,
-            r_total, windows, selfowned, early_start)
+    with span("tola"):
+        for it in range(iters):
+            if it == 0 and _C0 is not None:
+                C = _C0
+            else:
+                C = cost_matrix(jobs, policies, market, r_total, windows,
+                                selfowned, early_start, availability, backend)
+            lr, chosen, realized, availability = _tola_round(
+                jobs, policies, C, arrivals, d, Z, spec, rng, market,
+                r_total, windows, selfowned, early_start, round_=it)
 
     fixed = (C * Z[:, None]).sum(axis=0) / Z.sum()
     return TolaResult(chosen=chosen, weights=lr.weights[0, 0],
@@ -236,20 +245,23 @@ def run_tola_scenarios(
 
     avails: list | None = None
     iters = 1 + (pool_iters if r_total > 0 else 0)
-    for it in range(iters):
-        res = evaluate_grid(
-            jobs, policies, markets, r_total, windows=windows,
-            selfowned=selfowned, early_start=early_start, pool="dedicated",
-            availability=avails, backend=backend, mesh=mesh)
-        C = res.unit_cost
-        rounds = [
-            _tola_round(jobs, policies, C[s], arrivals, d, Z, spec, rngs[s],
-                        markets[s], r_total, windows, selfowned, early_start)
-            for s in range(S)
-        ]
-        avails = [r[3] for r in rounds]
-        if any(a is None for a in avails):
-            avails = None  # r_total == 0: nothing to refine against
+    with span("tola"):
+        for it in range(iters):
+            res = evaluate_grid(
+                jobs, policies, markets, r_total, windows=windows,
+                selfowned=selfowned, early_start=early_start,
+                pool="dedicated", availability=avails, backend=backend,
+                mesh=mesh)
+            C = res.unit_cost
+            rounds = [
+                _tola_round(jobs, policies, C[s], arrivals, d, Z, spec,
+                            rngs[s], markets[s], r_total, windows, selfowned,
+                            early_start, round_=it, scenario=s)
+                for s in range(S)
+            ]
+            avails = [r[3] for r in rounds]
+            if any(a is None for a in avails):
+                avails = None  # r_total == 0: nothing to refine against
 
     return [
         TolaResult(chosen=chosen, weights=lr.weights[0, 0],

@@ -40,13 +40,13 @@ from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
-from repro.obs import METRICS, maybe_snapshot, span
+from repro.obs import maybe_snapshot, span
 
 from repro.core.market import SpotMarket
 from repro.core.scheduler import Policy
 from repro.core.types import ChainJob
 from repro.engine.mesh import as_scenario_mesh
-from repro.engine.plan import build_grid_plan
+from repro.engine.plan import OUT_KEYS as _OUT_KEYS, build_grid_plan
 from repro.engine.result import EngineResult
 from repro.engine.scenarios import as_source
 
@@ -56,7 +56,6 @@ __all__ = ["evaluate_grid", "evaluate_grid_chunks", "GridChunk",
 _BACKENDS = ("numpy", "jax", "pallas")
 _PLAN_BACKENDS = ("host", "device")
 _REDUCES = ("stack", "mean")
-_OUT_KEYS = ("spot_cost", "ondemand_cost", "spot_work", "ondemand_work")
 
 
 def available_backends() -> list[str]:
@@ -315,6 +314,7 @@ def evaluate_grid_chunks(
 
     def _iter():
         J, P = gplan.n_jobs, gplan.n_policies
+        rows = len(gplan.groups) * J
         wl = np.maximum(gplan.workload, 1e-12)
         stream = source.chunks(chunk, device=(backend != "numpy"),
                                mesh=mesh)
@@ -325,11 +325,11 @@ def evaluate_grid_chunks(
                 with span("synth", s0=s0, s1=s1, overlap=overlap) as sp_s:
                     batch.prepare()
                 out = {k: np.zeros((s1 - s0, J, P)) for k in _OUT_KEYS}
-                with span("eval", s0=s0, s1=s1, backend=backend) as sp_e:
+                with span("eval", s0=s0, s1=s1, backend=backend, rows=rows,
+                          cells=(s1 - s0) * J * P) as sp_e:
                     _dispatch(backend, gplan, batch, early_start, out,
                               interpret, mesh)
             synth_t, eval_t = sp_s.seconds, sp_e.seconds
-            _chunk_metrics(backend, synth_t, eval_t)
             unit = (out["spot_cost"] + out["ondemand_cost"]) \
                 / wl[None, :, None]
             yield GridChunk(s0=s0, s1=s1, unit_cost=unit, out=out,
@@ -338,13 +338,6 @@ def evaluate_grid_chunks(
                                      "overlap": overlap})
 
     return _iter()
-
-
-def _chunk_metrics(backend, synth_t, eval_t):
-    if METRICS.enabled:
-        h = METRICS.histogram("engine.chunk_seconds")
-        h.observe(synth_t, phase="synth", backend=backend)
-        h.observe(eval_t, phase="eval", backend=backend)
 
 
 def evaluate_grid(
@@ -426,6 +419,7 @@ def evaluate_grid(
                     pool, availability, backend, plan_backend,
                     scenario_chunk, mesh, overlap)
         S, J, P = source.n_scenarios, gplan.n_jobs, gplan.n_policies
+        rows = len(gplan.groups) * J
         root.set(backend=backend, scenarios=S, overlap=overlap)
 
         if reduce == "stack":
@@ -452,7 +446,8 @@ def evaluate_grid(
                     out_chunk = {k: v[s0:s1] for k, v in out.items()}
                 else:
                     out_chunk = {k: v[:s1 - s0] for k, v in buf.items()}
-                with span("eval", s0=s0, s1=s1, backend=backend) as sp_e:
+                with span("eval", s0=s0, s1=s1, backend=backend, rows=rows,
+                          cells=(s1 - s0) * J * P) as sp_e:
                     _dispatch(backend, gplan, batch, early_start, out_chunk,
                               interpret, mesh)
                 eval_t = sp_e.seconds
@@ -461,14 +456,10 @@ def evaluate_grid(
                     acc[k] += out_chunk[k].sum(axis=0)
             synth_total += synth_t
             eval_total += eval_t
-            _chunk_metrics(backend, synth_t, eval_t)
             chunk_timings.append({"scenarios": [s0, s1], "synth": synth_t,
                                   "eval": eval_t})
         if reduce == "mean":
             out = {k: v[None] / S for k, v in acc.items()}
-    if METRICS.enabled:
-        METRICS.gauge("engine.scenarios_per_sec").set(
-            S / max(root.seconds, 1e-12), backend=backend)
 
     per_scenario = gplan.per_scenario
     so_shape = (S, J, P) if per_scenario else (J, P)

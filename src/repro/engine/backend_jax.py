@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.engine.plan import concat_rows, scenario_cat
+from repro.engine.plan import OUT_KEYS, concat_rows, scenario_cat
 from repro.kernels.ref import chain_costs_ref, policy_cost_ref
 from repro.obs import record_jit, span
 
@@ -180,57 +180,57 @@ def run(gplan, batch, early_start: bool, out, mesh=None) -> None:
             # synthesized on device (a spec source), host f64 otherwise;
             # padded + sharded over "data" under a mesh.
             A, C = batch.stacked(bid)
-            A, C = f32(A), f32(C)
-            ends = concat_rows([g.plan.ends for g in gpad])
-            if ps:
-                z_t = _scen_rows(scenario_cat(gpad, "z_t", S), rows)
-                d_eff = _scen_rows(scenario_cat(gpad, "d_eff", S), rows)
-            else:
-                z_t = concat_rows([g.z_t for g in gpad])
-                d_eff = concat_rows([g.d_eff for g in gpad])
+            with span("eval.stack"):
+                A, C = f32(A), f32(C)
+                ends = concat_rows([g.plan.ends for g in gpad])
+                if ps:
+                    z_t = _scen_rows(scenario_cat(gpad, "z_t", S), rows)
+                    d_eff = _scen_rows(scenario_cat(gpad, "d_eff", S), rows)
+                else:
+                    z_t = concat_rows([g.z_t for g in gpad])
+                    d_eff = concat_rows([g.d_eff for g in gpad])
+                if early_start:
+                    arrival = np.tile(gplan.arrival, Gp)
+                    if ps:
+                        pins = _scen_rows(scenario_cat(gpad, "pins", S), rows)
+                    else:
+                        pins = concat_rows([g.pins for g in gpad])
+                    args = (A, C, f32(arrival), f32(ends), f32(z_t),
+                            f32(d_eff), jnp.asarray(pins), scalar(p_od),
+                            scalar(slot))
+                else:
+                    starts = concat_rows([g.plan.starts for g in gpad])
+                    R, L = ends.shape
+                    if ps:
+                        args = (A, C, f32(starts.ravel()), f32(ends.ravel()),
+                                f32(z_t).reshape(rows, R * L),
+                                f32(d_eff).reshape(rows, R * L),
+                                scalar(p_od), scalar(slot))
+                    else:
+                        args = (A, C, f32(starts.ravel()), f32(ends.ravel()),
+                                f32(z_t.reshape(R * L)),
+                                f32(d_eff.reshape(R * L)), scalar(p_od),
+                                scalar(slot))
             if early_start:
-                arrival = np.tile(gplan.arrival, Gp)
-                if ps:
-                    pins = _scen_rows(scenario_cat(gpad, "pins", S), rows)
-                    args = (A, C, f32(arrival), f32(ends), f32(z_t),
-                            f32(d_eff), jnp.asarray(pins), scalar(p_od),
-                            scalar(slot))
-                    record_jit("engine.eval.chain_ps" + sfx, chain_ps_fn,
-                               *args)
-                    res = chain_ps_fn(*args)
-                else:
-                    pins = concat_rows([g.pins for g in gpad])
-                    args = (A, C, f32(arrival), f32(ends), f32(z_t),
-                            f32(d_eff), jnp.asarray(pins), scalar(p_od),
-                            scalar(slot))
-                    record_jit("engine.eval.chain" + sfx, chain_fn, *args)
-                    res = chain_fn(*args)
+                key, fn = (("chain_ps", chain_ps_fn) if ps
+                           else ("chain", chain_fn))
             else:
-                starts = concat_rows([g.plan.starts for g in gpad])
-                R, L = ends.shape
-                if ps:
-                    args = (A, C, f32(starts.ravel()), f32(ends.ravel()),
-                            f32(z_t).reshape(rows, R * L),
-                            f32(d_eff).reshape(rows, R * L), scalar(p_od),
-                            scalar(slot))
-                    record_jit("engine.eval.task_ps" + sfx, task_ps_fn,
-                               *args)
-                    res = task_ps_fn(*args)
-                else:
-                    args = (A, C, f32(starts.ravel()), f32(ends.ravel()),
-                            f32(z_t.reshape(R * L)),
-                            f32(d_eff.reshape(R * L)), scalar(p_od),
-                            scalar(slot))
-                    record_jit("engine.eval.task" + sfx, task_fn, *args)
-                    res = task_fn(*args)
+                key, fn = ("task_ps", task_ps_fn) if ps else ("task", task_fn)
+            record_jit("engine.eval." + key + sfx, fn, *args)
+            res = fn(*args)
+            if not early_start:
                 res = {k: v.reshape(rows, R, L).sum(axis=2)
                        for k, v in res.items() if k != "finish"}
-            shape = (S, Gp, J)
-            for key in ("spot_cost", "ondemand_cost", "spot_work",
-                        "ondemand_work"):
-                # [:S] drops the mesh padding rows (duplicates of the last
-                # scenario) before the host scatter; indexing only the
-                # real ``groups`` below masks the padded group lanes.
-                vals = np.asarray(res[key], np.float64)[:S].reshape(shape)
-                for gi, g in enumerate(groups):
-                    out[key][:, :, g.policy_idx] = vals[:, gi, :, None]
+            with span("eval.wait"):
+                jax.block_until_ready(res)
+            # [:S] drops the mesh padding rows (duplicates of the last
+            # scenario) before the host scatter; indexing only the real
+            # ``groups`` below masks the padded group lanes.
+            with span("eval.fetch"):
+                vals = {k: np.asarray(res[k], np.float64)[:S].reshape(
+                            (S, Gp, J))
+                        for k in OUT_KEYS}
+            with span("eval.scatter"):
+                for k in OUT_KEYS:
+                    for gi, g in enumerate(groups):
+                        out[k][:, :, g.policy_idx] = vals[k][:, gi, :, None]

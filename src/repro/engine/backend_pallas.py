@@ -21,8 +21,8 @@ import functools
 
 import numpy as np
 
-from repro.engine.plan import concat_rows, scenario_cat
-from repro.obs import record_jit
+from repro.engine.plan import OUT_KEYS, concat_rows, scenario_cat
+from repro.obs import record_jit, span
 
 __all__ = ["run"]
 
@@ -63,95 +63,103 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
         B = len(bids)
         per_scenario = gplan.per_scenario
         R_max = max(len(gs) for gs in groups_per_bid) * J
-        arrival = np.zeros((B, R_max))
-        for bi, groups in enumerate(groups_per_bid):
-            arrival[bi, :len(groups) * J] = np.tile(gplan.arrival,
-                                                    len(groups))
-        if batch.device:
-            # Device-synthesized chunk: the per-bid views are already f32
-            # jax arrays — stack them with jnp so the kernel consumes them
-            # without a host round trip.
-            AC = [batch.stacked(bid) for bid in bids]
-            A = jnp.stack([a for a, _ in AC])
-            C = jnp.stack([c for _, c in AC])
-        else:
-            A = np.zeros((B, S, batch.n_slots + 1), np.float32)
-            C = np.zeros_like(A)
-            for bi, bid in enumerate(bids):
-                A[bi], C[bi] = batch.stacked(bid)
-        if gplan.device:
-            # Device grid plan: build the zero-padded (B, ..., R_max, L)
-            # stacks with jnp so the plan tensors feed the kernel without a
-            # host round trip.
-            def pad(a, raxis):
-                if a.shape[raxis] == R_max:
-                    return a
-                w = [(0, 0)] * a.ndim
-                w[raxis] = (0, R_max - a.shape[raxis])
-                return jnp.pad(a, w)
-
-            raxis = 1 if per_scenario else 0  # row axis of the s-o stacks
-
-            def cat(groups, attr):
-                if per_scenario:
-                    return scenario_cat(groups, attr, S)
-                return concat_rows([getattr(g, attr) for g in groups])
-
-            ends = jnp.stack(
-                [pad(concat_rows([g.plan.ends for g in gs]), 0)
-                 for gs in groups_per_bid])
-            z_t = jnp.stack([pad(cat(gs, "z_t"), raxis)
-                             for gs in groups_per_bid])
-            d_eff = jnp.stack([pad(cat(gs, "d_eff"), raxis)
-                               for gs in groups_per_bid])
-            pins = jnp.stack([pad(cat(gs, "pins"), raxis)
-                              for gs in groups_per_bid])
-        else:
-            ends = np.zeros((B, R_max, L))
-            pshape = (B, S, R_max, L) if per_scenario else (B, R_max, L)
-            z_t = np.zeros(pshape)
-            d_eff = np.zeros(pshape)
-            pins = np.zeros(pshape, dtype=bool)
+        # Per-bid views first: their own ``views`` spans time them.
+        AC = [batch.stacked(bid) for bid in bids]
+        with span("eval.stack"):
+            arrival = np.zeros((B, R_max))
             for bi, groups in enumerate(groups_per_bid):
-                R = len(groups) * J
-                ends[bi, :R] = np.concatenate([g.plan.ends for g in groups])
-                if per_scenario:
-                    sl = (bi, slice(None), slice(0, R))
-                    cat = lambda attr: scenario_cat(groups, attr, S)
-                else:
-                    sl = (bi, slice(0, R))
-                    cat = lambda attr: np.concatenate(
-                        [getattr(g, attr) for g in groups])
-                z_t[sl] = cat("z_t")
-                d_eff[sl] = cat("d_eff")
-                pins[sl] = cat("pins")
+                arrival[bi, :len(groups) * J] = np.tile(gplan.arrival,
+                                                        len(groups))
+            if batch.device:
+                # Device-synthesized chunk: the per-bid views are already
+                # f32 jax arrays — stack them with jnp so the kernel
+                # consumes them without a host round trip.
+                A = jnp.stack([a for a, _ in AC])
+                C = jnp.stack([c for _, c in AC])
+            else:
+                A = np.zeros((B, S, batch.n_slots + 1), np.float32)
+                C = np.zeros_like(A)
+                for bi, (a, c) in enumerate(AC):
+                    A[bi], C[bi] = a, c
+            if gplan.device:
+                # Device grid plan: build the zero-padded (B, ..., R_max, L)
+                # stacks with jnp so the plan tensors feed the kernel
+                # without a host round trip.
+                def pad(a, raxis):
+                    if a.shape[raxis] == R_max:
+                        return a
+                    w = [(0, 0)] * a.ndim
+                    w[raxis] = (0, R_max - a.shape[raxis])
+                    return jnp.pad(a, w)
+
+                raxis = 1 if per_scenario else 0  # row axis of the s-o stacks
+
+                def cat(groups, attr):
+                    if per_scenario:
+                        return scenario_cat(groups, attr, S)
+                    return concat_rows([getattr(g, attr) for g in groups])
+
+                ends = jnp.stack(
+                    [pad(concat_rows([g.plan.ends for g in gs]), 0)
+                     for gs in groups_per_bid])
+                z_t = jnp.stack([pad(cat(gs, "z_t"), raxis)
+                                 for gs in groups_per_bid])
+                d_eff = jnp.stack([pad(cat(gs, "d_eff"), raxis)
+                                   for gs in groups_per_bid])
+                pins = jnp.stack([pad(cat(gs, "pins"), raxis)
+                                  for gs in groups_per_bid])
+            else:
+                ends = np.zeros((B, R_max, L))
+                pshape = (B, S, R_max, L) if per_scenario else (B, R_max, L)
+                z_t = np.zeros(pshape)
+                d_eff = np.zeros(pshape)
+                pins = np.zeros(pshape, dtype=bool)
+                for bi, groups in enumerate(groups_per_bid):
+                    R = len(groups) * J
+                    ends[bi, :R] = np.concatenate(
+                        [g.plan.ends for g in groups])
+                    if per_scenario:
+                        sl = (bi, slice(None), slice(0, R))
+                        cat = lambda attr: scenario_cat(groups, attr, S)
+                    else:
+                        sl = (bi, slice(0, R))
+                        cat = lambda attr: np.concatenate(
+                            [getattr(g, attr) for g in groups])
+                    z_t[sl] = cat("z_t")
+                    d_eff[sl] = cat("d_eff")
+                    pins[sl] = cat("pins")
         args = (A, C, arrival, ends, z_t, d_eff, pins)
         kw = dict(slot=slot, p_od=p_od, block_rows=block_rows,
                   interpret=interpret)
         record_jit("engine.eval.pallas_chain", chain, *args, **kw)
         res = chain(*args, **kw)
-        for key in ("spot_cost", "ondemand_cost", "spot_work",
-                    "ondemand_work"):
-            vals = np.asarray(res[key], np.float64)     # (B, S, R_max)
-            for bi, groups in enumerate(groups_per_bid):
-                per_g = vals[bi, :, :len(groups) * J].reshape(
-                    S, len(groups), J)
-                for gi, g in enumerate(groups):
-                    out[key][:, :, g.policy_idx] = per_g[:, gi, :, None]
+        with span("eval.wait"):
+            jax.block_until_ready(res)
+        with span("eval.fetch"):
+            vals = {key: np.asarray(res[key], np.float64)  # (B, S, R_max)
+                    for key in OUT_KEYS}
+        with span("eval.scatter"):
+            for key in OUT_KEYS:
+                for bi, groups in enumerate(groups_per_bid):
+                    per_g = vals[key][bi, :, :len(groups) * J].reshape(
+                        S, len(groups), J)
+                    for gi, g in enumerate(groups):
+                        out[key][:, :, g.policy_idx] = per_g[:, gi, :, None]
         return
 
     for bid, groups in zip(bids, groups_per_bid):
         A, C = batch.stacked(bid)               # (S, n_slots+1)
-        starts = concat_rows([g.plan.starts for g in groups])
-        ends = concat_rows([g.plan.ends for g in groups])
-        R, L = ends.shape
-        if gplan.per_scenario:
-            z_all = scenario_cat(groups, "z_t", S)       # (S, R, L)
-            d_all = scenario_cat(groups, "d_eff", S)
-        else:
-            z_one = concat_rows([g.z_t for g in groups])
-            d_one = concat_rows([g.d_eff for g in groups])
-        per_s = []
+        with span("eval.stack"):
+            starts = concat_rows([g.plan.starts for g in groups])
+            ends = concat_rows([g.plan.ends for g in groups])
+            R, L = ends.shape
+            if gplan.per_scenario:
+                z_all = scenario_cat(groups, "z_t", S)       # (S, R, L)
+                d_all = scenario_cat(groups, "d_eff", S)
+            else:
+                z_one = concat_rows([g.z_t for g in groups])
+                d_one = concat_rows([g.d_eff for g in groups])
+        launched = []
         for s in range(S):
             z_t = z_all[s] if gplan.per_scenario else z_one
             d_eff = d_all[s] if gplan.per_scenario else d_one
@@ -166,13 +174,16 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
                 r["ondemand_cost"] / p_od if p_od > 0
                 else jnp.maximum(flat(z_t) - r["spot_work"], 0.0)
                 * (flat(z_t) > 1e-15))
-            per_s.append({k: np.asarray(v, np.float64)
-                          .reshape(len(groups), J, L).sum(axis=2)
-                          for k, v in r.items() if k != "finish"})
-        vals = {k: np.stack([p[k] for p in per_s])
-                for k in per_s[0]}
-        for key in ("spot_cost", "ondemand_cost", "spot_work",
-                    "ondemand_work"):
-            v = vals[key]
-            for gi, g in enumerate(groups):
-                out[key][:, :, g.policy_idx] = v[:, gi, :, None]
+            launched.append(r)
+        with span("eval.wait"):
+            jax.block_until_ready(launched)
+        with span("eval.fetch"):
+            vals = {key: np.stack([np.asarray(r[key], np.float64)
+                                   .reshape(len(groups), J, L).sum(axis=2)
+                                   for r in launched])
+                    for key in OUT_KEYS}
+        with span("eval.scatter"):
+            for key in OUT_KEYS:
+                v = vals[key]
+                for gi, g in enumerate(groups):
+                    out[key][:, :, g.policy_idx] = v[:, gi, :, None]

@@ -58,9 +58,12 @@ from repro.core.scheduler import (
 from repro.core.types import ChainJob
 
 __all__ = ["EvalGroup", "GridPlan", "build_grid_plan", "scenario_cat",
-           "concat_rows", "distinct_window_params"]
+           "concat_rows", "distinct_window_params", "OUT_KEYS"]
 
 _PLAN_BACKENDS = ("host", "device")
+
+# The per-cell cost decomposition every eval backend writes into ``out``.
+OUT_KEYS = ("spot_cost", "ondemand_cost", "spot_work", "ondemand_work")
 
 # Dust threshold of the DEVICE residual-workload kill. The host oracle
 # zeroes residuals below 1e-9 * (z + 1) — the f64 cancellation floor of
@@ -296,8 +299,10 @@ def build_grid_plan(
             "chronological shared-pool replay is host code)")
 
     structure = _grid_structure(policies, r_total, windows)
-    arrays = job_arrays(jobs)
-    jobs_fp = _cache.fingerprint_job_arrays(arrays)
+    with span("plan.arrays"):
+        arrays = job_arrays(jobs)
+    with span("plan.fingerprint"):
+        jobs_fp = _cache.fingerprint_job_arrays(arrays)
     # Availability queries are opaque host callables — their results have
     # no fingerprint, so refined plans never enter the cross-call cache.
     use_cache = availability is None and _cache.enabled()
@@ -322,15 +327,17 @@ def _cache_lookup(s: _GridStructure, base: tuple, use_cache: bool):
     the missing groups actually need are recomputed, and building a
     subset of the Dealloc parameters is bit-identical to building all of
     them (``build_plans_batch`` vectorizes per parameter)."""
-    cached: dict[int, EvalGroup] = {}
-    if use_cache:
-        for gi in range(len(s.g_bid)):
-            rec = _cache.PLAN_CACHE.get((base, s.g_key[gi]))
-            if rec is not None:
-                cached[gi] = rec
-        _cache.plan_cache_events(hits=len(cached),
-                                 misses=len(s.g_bid) - len(cached))
-    miss = [gi for gi in range(len(s.g_bid)) if gi not in cached]
+    with span("plan.lookup") as sp:
+        cached: dict[int, EvalGroup] = {}
+        if use_cache:
+            for gi in range(len(s.g_bid)):
+                rec = _cache.PLAN_CACHE.get((base, s.g_key[gi]))
+                if rec is not None:
+                    cached[gi] = rec
+            _cache.plan_cache_events(hits=len(cached),
+                                     misses=len(s.g_bid) - len(cached))
+        miss = [gi for gi in range(len(s.g_bid)) if gi not in cached]
+        sp.set(hits=len(cached), misses=len(miss))
     return cached, miss
 
 

@@ -8,7 +8,9 @@ unconditionally (DESIGN.md Section 10):
   single timing source `EngineResult.timings` is derived from); full span
   records (nesting, attributes, timestamps) are captured only while a
   ``trace()`` context is active, and export to Chrome-trace/Perfetto JSON
-  or a flat JSONL event log.
+  or a flat JSONL event log.  While a tracer is installed and jax is
+  loaded, each span is mirrored as a ``jax.profiler.TraceAnnotation``, so
+  a JAX profile shows the spans on the device trace's clock.
 * :mod:`repro.obs.compiled` — compile-time introspection.  Engine call
   sites announce every cached jit program via ``record_jit(key, fn,
   *args)``; inside a ``capture()`` context the program is lowered,
@@ -17,8 +19,8 @@ unconditionally (DESIGN.md Section 10):
   shard tests into a standing metric.  Outside a capture context the hook
   is a single context-var read.
 * :mod:`repro.obs.metrics` — a counter/gauge/histogram registry with
-  labeled series (chunk latency, scenarios/sec, adaptive-adversary
-  escalations, learner weight entropy), snapshotted into
+  labeled series (plan-cache hits, adaptive-adversary escalations,
+  learner weight entropy), snapshotted into
   ``EngineResult.obs`` / ``StreamLearnResult.obs``.
 
 ``observe()`` composes all three for the common "turn everything on"

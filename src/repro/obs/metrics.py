@@ -6,10 +6,6 @@ attribute read per chunk.  ``METRICS.collecting()`` flips it on for a
 block (``repro.obs.observe()`` does this for you), after which the engine
 and learner record:
 
-* ``engine.chunk_seconds`` histogram, labels ``phase={synth,eval}``,
-  ``backend=...`` — per-chunk latency split.
-* ``engine.scenarios_per_sec`` gauge, label ``backend`` — end-to-end
-  streaming throughput of the last ``evaluate_grid`` call.
 * ``scenarios.adaptive_escalations`` counter, label ``to=stage`` — one
   increment per adaptive-adversary stage transition (periods -> phases ->
   locked), plus ``scenarios.adaptive_chunks`` per chunk served per stage.
@@ -24,6 +20,9 @@ and learner record:
 * ``engine.delta_groups_rescored`` counter — eval groups actually
   re-scored by :func:`repro.engine.cache.evaluate_grid_delta` (the
   unchanged remainder was spliced from the previous result).
+
+Per-chunk seconds are not a metric: they are the ``synth`` and ``eval``
+spans (and ``EngineResult.timings["chunks"]``).
 
 Snapshots (:meth:`MetricsRegistry.snapshot`) are plain JSON-able dicts
 attached to ``EngineResult.obs`` / ``StreamLearnResult.obs`` and dumped

@@ -12,10 +12,18 @@ Design constraints (ISSUE 7 / DESIGN.md Section 10):
   two ``perf_counter_ns`` calls — the same cost as the ad-hoc timers the
   spans replaced.
 * When a :func:`trace` context is active, finished spans are appended to
-  the tracer as flat :class:`SpanRecord` rows (id/parent/name/ts/seconds/
-  tid/attrs).  Nesting is tracked through a second ContextVar so the
-  records form a tree; generators iterated inside a span parent their
+  the tracer as flat :class:`SpanRecord` rows (id, parent, root, name,
+  ts, seconds, tid, attrs).  Nesting is tracked through a second ContextVar so
+  the records form a tree; generators iterated inside a span parent their
   spans correctly (plain generators run in the caller's context).
+  ``root`` is the id of the outermost span of the same tracer open when
+  the span started: every span of one ``evaluate_grid`` call or one TOLA
+  run shares it, the request id of the call.
+* While a tracer is installed and ``jax`` is already imported, each span
+  also opens and closes a ``jax.profiler.TraceAnnotation`` of its own
+  name, so a JAX profile (Perfetto, TensorBoard) shows the program's
+  spans in its host plane, on the device trace's clock.  Nothing imports
+  jax for this, and with no tracer installed no profiler call is made.
 
 Exporters: :meth:`Tracer.to_chrome` emits the Chrome trace-event JSON
 dialect (``ph: "X"`` complete events with ts/dur in microseconds) which
@@ -28,6 +36,7 @@ import dataclasses
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -53,6 +62,7 @@ class SpanRecord:
 
     id: int
     parent: int | None
+    root: int  # id of the outermost span open when this one started
     name: str
     ts: float  # seconds since tracer start
     seconds: float
@@ -63,7 +73,8 @@ class SpanRecord:
 class Span:
     """A timed region.  Usable with or without an active tracer."""
 
-    __slots__ = ("name", "attrs", "seconds", "id", "_t0", "_tracer", "_token", "_parent_id")
+    __slots__ = ("name", "attrs", "seconds", "id", "_t0", "_tracer", "_token",
+                 "_parent_id", "_root", "_ann")
 
     def __init__(self, name, attrs):
         self.name = name
@@ -82,8 +93,14 @@ class Span:
         if tracer is not None:
             self.id = tracer._next_id()
             parent = _ACTIVE.get()
-            self._parent_id = parent.id if parent is not None else None
+            if parent is not None and parent._tracer is tracer:
+                self._parent_id, self._root = parent.id, parent._root
+            else:
+                self._parent_id, self._root = None, self.id
             self._token = _ACTIVE.set(self)
+            self._ann = _annotation(self.name)
+            if self._ann is not None:
+                self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -92,9 +109,18 @@ class Span:
         self.seconds = (t1 - self._t0) * 1e-9
         tracer = self._tracer
         if tracer is not None:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
             _ACTIVE.reset(self._token)
             tracer._record(self, self._t0)
         return False
+
+
+def _annotation(name):
+    """A profiler annotation named ``name``, or None while jax is not
+    imported (``repro.obs`` never imports it)."""
+    profiler = sys.modules.get("jax.profiler")
+    return None if profiler is None else profiler.TraceAnnotation(name)
 
 
 def span(name, **attrs):
@@ -124,6 +150,7 @@ class Tracer:
         rec = SpanRecord(
             id=sp.id,
             parent=sp._parent_id,
+            root=sp._root,
             name=sp.name,
             ts=(t0_ns - self._t0) * 1e-9,
             seconds=sp.seconds,
@@ -170,6 +197,7 @@ class Tracer:
             args["span_id"] = r.id
             if r.parent is not None:
                 args["parent_id"] = r.parent
+            args["root_id"] = r.root
             events.append(
                 {
                     "name": r.name,
@@ -185,7 +213,8 @@ class Tracer:
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def to_jsonl(self):
-        """One JSON object per line: id/parent/name/ts/dur/tid/attrs."""
+        """One JSON object per line: id, parent, root, name, ts, dur, tid,
+        attrs."""
         lines = []
         for r in self.spans:
             lines.append(
@@ -193,6 +222,7 @@ class Tracer:
                     {
                         "id": r.id,
                         "parent": r.parent,
+                        "root": r.root,
                         "name": r.name,
                         "ts": r.ts,
                         "dur": r.seconds,

@@ -347,18 +347,25 @@ def test_metrics_kind_mismatch_raises():
 
 
 def test_engine_metrics_snapshot_on_result():
+    from repro.engine.plan import build_grid_plan
+
     jobs, horizon = _setup()
     spec = ScenarioSpec("fresh", horizon, 4, seed=1)
+    n_groups = len(build_grid_plan(jobs, GRID).groups)
     with METRICS.collecting(reset=True):
-        res = evaluate_grid(jobs, GRID, spec, backend="numpy",
-                            scenario_chunk=2)
+        for _ in range(2):
+            res = evaluate_grid(jobs, GRID, spec, backend="numpy",
+                                scenario_chunk=2)
     assert res.obs is not None
     m = res.obs["metrics"]
-    series = m["engine.chunk_seconds"]["series"]
-    by_phase = {tuple(sorted(s["labels"].items())): s for s in series}
-    key = (("backend", "numpy"), ("phase", "eval"))
-    assert by_phase[key]["count"] == 2
-    assert "engine.scenarios_per_sec" in m
+    series = m["engine.plan_cache"]["series"]
+    by_event = {s["labels"]["event"]: s["value"] for s in series}
+    # one lookup per eval group per call; the second call hits every group
+    assert by_event.get("hit", 0.0) + by_event.get("miss", 0.0) \
+        == 2 * n_groups
+    assert by_event["hit"] >= n_groups
+    assert "engine.chunk_seconds" not in m
+    assert "engine.scenarios_per_sec" not in m
     # no active collection -> no snapshot
     res2 = evaluate_grid(jobs, GRID, spec, backend="numpy")
     assert res2.obs is None
@@ -476,3 +483,235 @@ def test_streamed_observation_end_to_end(tmp_path):
         "collective_counts"]["all-reduce"] == 1
     caches = out.obs["compiled"]["factory_caches"]
     assert caches["learn.fold"]["misses"] >= 1
+
+
+# --------------------------------------------------------------------------
+# Spans on the profiler's clock: mirrored as jax.profiler annotations in
+# the profile's host plane while a tracer is installed, absent otherwise.
+# --------------------------------------------------------------------------
+
+def _profiled(fn, log_dir):
+    """Run ``fn`` under the JAX profiler; the host plane's events as
+    {name: [(start_ns, duration_ns), ...]}."""
+    import glob
+    import os
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events: dict = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns))
+    return events
+
+
+def _small_jax_grid():
+    jobs, horizon = _setup()
+    spec = ScenarioSpec("fresh", horizon, 2, seed=4)
+    run = lambda: evaluate_grid(jobs, GRID, spec, backend="jax")
+    run()  # compile outside the profile
+    return run
+
+
+def test_spans_mirrored_in_profiler_host_plane(tmp_path):
+    pytest.importorskip("jax")
+    run = _small_jax_grid()
+    tracers = []
+
+    def traced():
+        with obs.tracing() as tr:
+            run()
+        tracers.append(tr)
+
+    events = _profiled(traced, tmp_path)
+    (tr,) = tracers
+    assert {"prepare_stream", "plan.fingerprint", "eval.wait"} <= {
+        r.name for r in tr.spans}
+    for name in {r.name for r in tr.spans}:
+        recs = sorted(tr.named(name), key=lambda r: r.ts)
+        evs = sorted(events.get(name, []))
+        assert len(evs) == len(recs), name
+        for rec, (_, dur_ns) in zip(recs, evs):
+            assert abs(dur_ns * 1e-9 - rec.seconds) <= max(
+                0.05 * rec.seconds, 50e-6), (name, dur_ns, rec.seconds)
+
+
+def test_no_profiler_events_without_tracer(tmp_path):
+    pytest.importorskip("jax")
+    run = _small_jax_grid()
+    with obs.tracing() as tr:
+        run()
+    names = {r.name for r in tr.spans}
+
+    def untraced_then_marker():
+        run()
+        with obs.tracing():
+            with span("profiler_was_on"):
+                pass
+
+    events = _profiled(untraced_then_marker, tmp_path)
+    assert "profiler_was_on" in events
+    assert not names & set(events)
+
+
+# --------------------------------------------------------------------------
+# The span tree: children of prepare_stream and eval, the TOLA round, and
+# one request id per call.
+# --------------------------------------------------------------------------
+
+def _kids(tr, rec):
+    return sorted(r.name for r in tr.children(rec.id))
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_evaluate_grid_span_tree(backend):
+    pytest.importorskip("jax")
+    jobs, horizon = _setup()
+    spec = ScenarioSpec("fresh", horizon, 2, seed=6)
+    with obs.tracing() as tr:
+        evaluate_grid(jobs, GRID, spec, backend=backend)
+    (root,) = tr.roots()
+    assert root.name == "evaluate_grid"
+    (prep,) = tr.named("prepare_stream")
+    assert prep.parent == root.id
+    assert {"plan.arrays", "plan.fingerprint", "plan.lookup"} <= set(
+        _kids(tr, prep))
+    (lookup,) = tr.named("plan.lookup")
+    assert lookup.attrs["hits"] + lookup.attrs["misses"] >= 1
+    (ev,) = tr.named("eval")
+    J, P = len(jobs), len(GRID)
+    assert ev.attrs["cells"] == 2 * J * P
+    assert ev.attrs["rows"] % J == 0 and ev.attrs["rows"] >= J
+    phases = ["eval.fetch", "eval.scatter", "eval.stack", "eval.wait"]
+    if backend == "pallas":
+        # one launch for every bid: the phases sit right under eval
+        assert set(phases) <= set(_kids(tr, ev))
+        for name in phases:
+            assert len(tr.named(name)) == 1
+    else:
+        # one program per bid: the phases sit under each eval.bid
+        bids = tr.named("eval.bid")
+        assert bids and all(b.parent == ev.id for b in bids)
+        for b in bids:
+            assert set(phases) <= set(_kids(tr, b))
+        for name in phases:
+            assert len(tr.named(name)) == len(bids)
+
+
+def _tola_inputs():
+    from repro.core import SpotMarket
+
+    jobs, horizon = _setup(n=10)
+    markets = [SpotMarket(horizon, seed=s) for s in (0, 1)]
+    return jobs, markets
+
+
+def test_tola_span_tree():
+    from repro.core import run_tola_scenarios
+
+    jobs, markets = _tola_inputs()
+    with obs.tracing() as tr:
+        run_tola_scenarios(jobs, GRID, markets, r_total=4, pool_iters=1,
+                           backend="numpy")
+    (root,) = tr.roots()
+    assert root.name == "tola"
+    rounds = tr.named("tola.round")
+    assert sorted((r.attrs["round"], r.attrs["scenario"]) for r in rounds) \
+        == [(0, 0), (0, 1), (1, 0), (1, 1)]
+    for r in rounds:
+        assert r.parent == root.id
+        assert _kids(tr, r) == ["replay", "tola.availability", "tola.plans",
+                                "tola.pool", "tola.realize"]
+    assert [r.parent for r in tr.named("evaluate_grid")] == [root.id] * 2
+
+
+def test_single_market_tola_has_one_root():
+    from repro.core import run_tola
+
+    jobs, markets = _tola_inputs()
+    with obs.tracing() as tr:
+        run_tola(jobs, GRID, markets[0], r_total=4, pool_iters=1,
+                 backend="numpy")
+    (root,) = tr.roots()
+    assert root.name == "tola"
+    assert len(tr.named("tola.round")) == 2
+    assert {r.root for r in tr.spans} == {root.id}
+
+
+def test_every_span_carries_its_request_id(tmp_path):
+    from repro.core import run_tola_scenarios
+
+    jobs, horizon = _setup()
+    spec = ScenarioSpec("fresh", horizon, 2, seed=7)
+    tjobs, markets = _tola_inputs()
+    with obs.tracing() as tr:
+        evaluate_grid(jobs, GRID, spec, backend="numpy")
+        run_tola_scenarios(tjobs, GRID, markets, r_total=4, pool_iters=1,
+                           backend="numpy")
+    roots = tr.roots()
+    assert [r.name for r in roots] == ["evaluate_grid", "tola"]
+    by_id = {r.id: r for r in tr.spans}
+    for r in tr.spans:
+        # the root's own id, reached by walking up the parents
+        top = r
+        while top.parent is not None:
+            top = by_id[top.parent]
+        assert r.root == top.id
+    assert {r.root for r in tr.spans} == {roots[0].id, roots[1].id}
+    # both exporters carry it
+    chrome = tr.to_chrome()["traceEvents"]
+    assert [ev["args"]["root_id"] for ev in chrome] == [
+        r.root for r in tr.spans]
+    lines = tr.to_jsonl().splitlines()
+    assert [json.loads(line)["root"] for line in lines] == [
+        r.root for r in tr.spans]
+
+
+def test_root_restarts_in_a_nested_tracer():
+    with obs.tracing() as outer:
+        with span("outer_call"):
+            with obs.tracing() as inner:
+                with span("inner_call"):
+                    with span("leaf"):
+                        pass
+    (top,) = inner.named("inner_call")
+    assert top.parent is None and top.root == top.id
+    assert inner.named("leaf")[0].root == top.id
+    assert outer.spans[0].root == outer.spans[0].id
+
+
+def test_obs_spans_work_without_jax():
+    """``repro.obs`` neither imports jax nor needs it: with jax made
+    unimportable, a traced span records and nothing pulls jax in."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "sys.modules['jax'] = None\n"
+        "from repro import obs\n"
+        "with obs.tracing() as tr:\n"
+        "    with obs.span('a'):\n"
+        "        with obs.span('b'):\n"
+        "            pass\n"
+        "assert [r.name for r in tr.spans] == ['b', 'a']\n"
+        "assert 'jax.profiler' not in sys.modules\n"
+    )
+    import os
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    subprocess.run([sys.executable, "-c", code], check=True, env=env)
