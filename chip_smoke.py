@@ -52,7 +52,7 @@ JOB_TYPE = 1             # chains of up to 49 tasks
 R_TOTAL = 600            # self-owned instances, from §6.1's r grid
 SCENARIO_SEED = 1000     # benchmarks/common.py's market seed offset
 S_JAX = 4                # scenarios of phase 2 (device time grows with S)
-S_PALLAS = 1             # phase 3: O(n_slots) comparison sweeps per lookup
+S_PALLAS = 1             # phase 3: one scenario, against phase 2's
 S_TOLA = 2               # phase 5: the learner replay is host float64
 S_FOUR = 2               # phase 6: one scenario per "data" shard; the
                          # unsharded reference runs on a single chip

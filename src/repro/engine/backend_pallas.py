@@ -12,7 +12,10 @@ Off-TPU the kernels run in interpret mode (slow, parity-testing only);
 ``interpret`` can be forced either way. Each launch, wrapper layout work
 included, is one jitted program announced to ``repro.obs`` as
 ``engine.eval.pallas_chain`` / ``engine.eval.pallas_task``; on a TPU its
-compiled text holds the Mosaic kernel as a ``tpu_custom_call``.
+compiled text holds the Mosaic kernel as a ``tpu_custom_call``. Inside
+``METRICS.collecting()`` each launch sets the gauge
+``engine.eval.lookup_tiles{kernel=chain|task}``: the lane tiles one slot
+lookup of the kernel reads, from the static slot count.
 """
 
 from __future__ import annotations
@@ -22,7 +25,7 @@ import functools
 import numpy as np
 
 from repro.engine.plan import OUT_KEYS, concat_rows, scenario_cat
-from repro.obs import record_jit, span
+from repro.obs import METRICS, record_jit, span
 
 __all__ = ["run"]
 
@@ -44,6 +47,8 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
         block_rows: int = 128) -> None:
     import jax
     import jax.numpy as jnp
+
+    from repro.kernels.policy_cost import lookup_tiles
 
     chain, task = _kernels()
     if interpret is None:
@@ -132,6 +137,9 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
         kw = dict(slot=slot, p_od=p_od, block_rows=block_rows,
                   interpret=interpret)
         record_jit("engine.eval.pallas_chain", chain, *args, **kw)
+        if METRICS.enabled:
+            METRICS.gauge("engine.eval.lookup_tiles").set(
+                lookup_tiles(batch.n_slots), kernel="chain")
         res = chain(*args, **kw)
         with span("eval.wait"):
             jax.block_until_ready(res)
@@ -169,6 +177,9 @@ def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
                     flat(starts), flat(ends), flat(z_t), flat(d_eff))
             kw = dict(slot=slot, p_od=p_od, interpret=interpret)
             record_jit("engine.eval.pallas_task", task, *args, **kw)
+            if METRICS.enabled:
+                METRICS.gauge("engine.eval.lookup_tiles").set(
+                    lookup_tiles(batch.n_slots), kernel="task")
             r = task(*args, **kw)
             r["ondemand_work"] = (
                 r["ondemand_cost"] / p_od if p_od > 0

@@ -8,19 +8,27 @@ monotone piecewise-linear inversions over the market's cumulative arrays
 core/simulate.py). That inner evaluation is this kernel.
 
 TPU adaptation (vs the numpy searchsorted implementation):
-  * the three cumulative arrays of one (bid, scenario) are stacked into one
-    (3, n_pad) VMEM block (~0.4 MB of data per array at ~30k slots),
-    loaded once per grid cell;
-  * searchsorted becomes a comparison-count reduction (monotone array:
-    index = #{k : cum[k] < target}) and every point gather (cum[k0],
-    cum[k0+1], ...) a masked select-and-sum, both swept 128-lane tile by
-    tile with a fori_loop — no data-dependent control flow, exact in f32;
-  * task rows live on SUBLANES as (BT, 1) columns, so one sweep step
-    compares a (1, 128) lane tile of the slot arrays against BT row
-    targets and accumulates into (BT, 128) lane partials that are reduced
-    across lanes once per sweep.  Row vectors enter and leave in their
-    lane-dense HBM layout and are turned into columns (and back) with a
-    diagonal select-and-reduce, which Mosaic lowers to plain VPU/XLU ops.
+  * the three cumulative arrays of one (bid, scenario) are cut into lane
+    tiles of 128 slots and laid out as one (n_tiles, 3 x 128) VMEM block,
+    row j holding tile j of A, C and H side by side, next to a small
+    (3, n_tiles) block of tile heads (each tile's first entry); n_tiles is
+    lane-padded to a multiple of 128, the slots past the horizon hold a
+    value above every query target; both are loaded once per grid cell;
+  * every slot lookup reads two levels, about two lane tiles instead of
+    the whole horizon: searchsorted (index = #{k : cum[k] < target} of a
+    monotone array) counts the heads below the target, which names the
+    one tile that holds the crossing, then counts inside that tile; a
+    point gather (cum[k0], cum[k0+1], ...) reads tile k // 128 at lane
+    k % 128, and the head of the next tile where k + 1 crosses into it;
+  * each row's tile is fetched by a one-hot matmul on the MXU
+    (onehot(BT, n_tiles) @ tiles at HIGHEST precision, f32 result), which
+    returns the stored f32 values bit for bit — no data-dependent control
+    flow, exact in f32;
+  * task rows live on SUBLANES as (BT, 1) columns, so the heads count and
+    the in-tile count compare a lane row against BT row targets at once.
+    Row vectors enter and leave in their lane-dense HBM layout and are
+    turned into columns (and back) with a diagonal select-and-reduce,
+    which Mosaic lowers to plain VPU/XLU ops.
 
 Block shapes follow the TPU tiling rule: every block's last two dims are
 multiples of (8, 128) or the whole array dims (leading dims squeezed with
@@ -43,13 +51,14 @@ from jax.experimental import pallas as pl
 from repro.core.simulate import FLEX_ABS as _FLEX_ABS
 from repro.core.simulate import FLEX_REL as _FLEX_REL
 
-__all__ = ["policy_cost", "policy_cost_chain"]
+__all__ = ["lookup_tiles", "policy_cost", "policy_cost_chain"]
 
 _LANES = 128
-_UNROLL = 4                       # lane tiles per sweep-loop iteration
-_CHUNK = _LANES * _UNROLL         # slot arrays are padded to this multiple
-_BIG = 3.4e38                     # pad value: above every query target
-_A, _C, _H = 0, 1, 2              # rows of the stacked (3, n_pad) slot block
+# Pad value: above every query target, and a power of two, so it stays
+# finite and exact in every bf16 part of the MXU's f32 passes.
+_BIG = 2.0 ** 100
+_A, _C, _H = 0, 1, 2              # slot arrays, in their order in a tile row
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _eye(n: int):
@@ -69,43 +78,68 @@ def to_row(col, n: int):
     return jnp.sum(jnp.where(_eye(n), col, zero), axis=0, keepdims=True)
 
 
-def _sweep(cum_ref, BT: int, gathers=(), counts=()):
-    """One pass over the stacked slot arrays.
-
-    ``gathers``: ``(idx, rows)`` pairs — return ``cum[r][idx]`` for every
-    ``r`` in ``rows`` (one lane mask shared by the rows). ``counts``:
-    ``(target, r)`` pairs — return ``#{k : cum[r][k] < target}``. All
-    operands are (BT, 1) columns; results come back in the same order.
-    """
-    n_pad = cum_ref.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (BT, _LANES), 1)
-    n_g = sum(len(rows) for _, rows in gathers)
-
-    def body(c, carry):
-        g_acc, c_acc = list(carry[0]), list(carry[1])
-        for u in range(_UNROLL):
-            base = pl.multiple_of(c * _CHUNK + u * _LANES, _LANES)
-            tile = [cum_ref[pl.ds(r, 1), pl.ds(base, _LANES)]
-                    for r in range(3)]
-            gi = 0
-            for idx, rows in gathers:
-                hit = lane == (idx - base)
-                for r in rows:
-                    g_acc[gi] = g_acc[gi] + jnp.where(hit, tile[r], 0.0)
-                    gi += 1
-            for ci, (tgt, r) in enumerate(counts):
-                c_acc[ci] = c_acc[ci] + (tile[r] < tgt).astype(jnp.int32)
-        return tuple(g_acc), tuple(c_acc)
-
-    init = (tuple(jnp.zeros((BT, _LANES), jnp.float32) for _ in range(n_g)),
-            tuple(jnp.zeros((BT, _LANES), jnp.int32) for _ in counts))
-    g_acc, c_acc = jax.lax.fori_loop(0, n_pad // _CHUNK, body, init)
-    lane_sum = lambda a: jnp.sum(a, axis=1, keepdims=True)
-    return [lane_sum(a) for a in g_acc], [lane_sum(a) for a in c_acc]
+def _n_tiles(n_slots: int) -> int:
+    """Lane tiles of the padded slot arrays, lane-padded to a multiple of
+    128 so the tile heads form whole lane tiles."""
+    t = -(-(n_slots + 1) // _LANES)
+    return -(-t // _LANES) * _LANES
 
 
-def _task_costs(cum_ref, start, end, z_t, d_eff, *, n_slots: int,
-                slot: float, p_od: float, BT: int):
+def lookup_tiles(n_slots: int) -> int:
+    """Lane tiles one slot lookup reads: the heads row, plus one tile."""
+    return _n_tiles(n_slots) // _LANES + 1
+
+
+def _fetch(tiles_ref, t, first: int, n: int, BT: int):
+    """Lane tile ``t`` ((BT, 1) tile indices) of the ``n`` slot arrays from
+    ``first`` on, one row per task: a one-hot matmul. Returns (BT, n*128)."""
+    nt = tiles_ref.shape[0]
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (BT, nt), 1) == t
+              ).astype(jnp.float32)
+    rhs = tiles_ref[:, pl.ds(first * _LANES, n * _LANES)]
+    return jnp.dot(onehot, rhs, precision=_HI,
+                   preferred_element_type=jnp.float32)
+
+
+def _pick(vals, idx):
+    """vals[i, idx[i]] of a (BT, w) block (or a (1, w) row) for (BT, 1)
+    indices; an index outside [0, w) picks 0."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], vals.shape[1]),
+                                    1)
+    return jnp.sum(jnp.where(lane == idx, vals, 0.0), axis=1, keepdims=True)
+
+
+def _gather2(tiles_ref, heads_ref, k, BT: int):
+    """(A[k], C[k], A[k+1], C[k+1]) for (BT, 1) slot indices 0 <= k <
+    n_slots: tile k // 128 holds k, and k + 1 too unless k % 128 == 127,
+    where it is the next tile's head."""
+    t = k // _LANES
+    lane = k - t * _LANES
+    ac = _fetch(tiles_ref, t, _A, 2, BT)
+    wrap = lane == _LANES - 1
+    out0, out1 = [], []
+    for r in (_A, _C):
+        tile = ac[:, r * _LANES:(r + 1) * _LANES]
+        out0.append(_pick(tile, lane))
+        out1.append(jnp.where(wrap, _pick(heads_ref[pl.ds(r, 1), :], t + 1),
+                              _pick(tile, lane + 1)))
+    return (*out0, *out1)
+
+
+def _count(tiles_ref, heads_ref, target, r: int, BT: int):
+    """#{k : cum[r][k] < target} of a nondecreasing cum[r], for (BT, 1)
+    targets: count the tile heads below the target, then the lanes below
+    it in the last tile whose head is (tile 0 if none is). Returns the
+    count, that tile's index and the tile."""
+    below = (heads_ref[pl.ds(r, 1), :] < target).astype(jnp.int32)
+    t = jnp.maximum(jnp.sum(below, axis=1, keepdims=True) - 1, 0)
+    tile = _fetch(tiles_ref, t, r, 1, BT)
+    lanes = jnp.sum((tile < target).astype(jnp.int32), axis=1, keepdims=True)
+    return t * _LANES + lanes, t, tile
+
+
+def _task_costs(tiles_ref, heads_ref, start, end, z_t, d_eff, *,
+                n_slots: int, slot: float, p_od: float, BT: int):
     """Closed-form costs of BT tasks, mirroring ``kernels/ref.py::_task_sim``
     (same targets, same tie handling). Operands are (BT, 1) columns."""
     d_safe = jnp.where(d_eff > 0, d_eff, 1.0)
@@ -113,8 +147,7 @@ def _task_costs(cum_ref, start, end, z_t, d_eff, *, n_slots: int,
 
     # Pass 1: interpolated A0/C0 at `start`.
     k0 = jnp.clip((start / slot).astype(jnp.int32), 0, n_slots - 1)
-    (a_k0, c_k0, a_k1, c_k1), _ = _sweep(
-        cum_ref, BT, gathers=[(k0, (_A, _C)), (k0 + 1, (_A, _C))])
+    a_k0, c_k0, a_k1, c_k1 = _gather2(tiles_ref, heads_ref, k0, BT)
     frac = start - k0.astype(jnp.float32) * slot
     A0 = a_k0 + (a_k1 - a_k0) / slot * frac
     C0 = c_k0 + (c_k1 - c_k0) / slot * frac
@@ -123,14 +156,16 @@ def _task_costs(cum_ref, start, end, z_t, d_eff, *, n_slots: int,
     # Pass 2: the two inverse-query counts.
     h_target = H0 + (end - start) - need
     a_target = A0 + need
-    _, (cntH, cntA) = _sweep(cum_ref, BT,
-                             counts=[(h_target, _H), (a_target, _A)])
+    cntH, tH, tileH = _count(tiles_ref, heads_ref, h_target, _H, BT)
+    cntA, tA, tileA = _count(tiles_ref, heads_ref, a_target, _A, BT)
 
-    # Pass 3: invert H and A at the counted indices.
+    # Pass 3: invert H and A at the counted indices. Index cnt - 1 lies in
+    # the tile the count read wherever the result is used (1 <= cnt <=
+    # n_slots: that tile's head is below the target; cnt == 0: tile 0).
     iH = jnp.clip(cntH, 1, n_slots)
     iA = jnp.clip(cntA, 1, n_slots)
-    (h_prev, a_prev), _ = _sweep(cum_ref, BT,
-                                 gathers=[(iH - 1, (_H,)), (iA - 1, (_A,))])
+    h_prev = _pick(tileH, iH - 1 - tH * _LANES)
+    a_prev = _pick(tileA, iA - 1 - tA * _LANES)
     # Flexibility epsilon (same constants as core.simulate.FLEX_REL /
     # FLEX_ABS): zero-slack tasks must turn at start deterministically in f32.
     no_flex = (end - start) - need <= jnp.maximum(
@@ -149,8 +184,7 @@ def _task_costs(cum_ref, start, end, z_t, d_eff, *, n_slots: int,
 
     # Pass 4: A/C at t_end.
     ke = jnp.clip((t_end / slot).astype(jnp.int32), 0, n_slots - 1)
-    (a_e0, c_e0, a_e1, c_e1), _ = _sweep(
-        cum_ref, BT, gathers=[(ke, (_A, _C)), (ke + 1, (_A, _C))])
+    a_e0, c_e0, a_e1, c_e1 = _gather2(tiles_ref, heads_ref, ke, BT)
     frace = t_end - ke.astype(jnp.float32) * slot
     A_end = a_e0 + (a_e1 - a_e0) / slot * frace
     C_end = c_e0 + (c_e1 - c_e0) / slot * frace
@@ -169,27 +203,38 @@ def _task_costs(cum_ref, start, end, z_t, d_eff, *, n_slots: int,
     }
 
 
+def _two_level(cum):
+    """(..., 3, n_slots+1) stacked [A, C, H] -> the two levels of the slot
+    lookup: ``tiles`` (..., n_tiles, 3*128), row j holding lane tile j of
+    A, C and H side by side, and ``heads`` (..., 3, n_tiles), each tile's
+    first entry; padded with a value above every query target."""
+    n1 = cum.shape[-1]
+    nt = _n_tiles(n1 - 1)
+    widths = [(0, 0)] * (cum.ndim - 1) + [(0, nt * _LANES - n1)]
+    cum = jnp.pad(cum, widths, constant_values=_BIG)
+    lead = cum.shape[:-2]
+    cum = cum.reshape(lead + (3, nt, _LANES))
+    tiles = jnp.swapaxes(cum, -3, -2).reshape(lead + (nt, 3 * _LANES))
+    return tiles, cum[..., 0]
+
+
 def _stack_cum(A_cum, C_cum, slot: float):
-    """(..., n_slots+1) A/C -> (..., 3, n_pad) stacked [A, C, H] slot
-    arrays, padded with a value above every query target."""
+    """(..., n_slots+1) A/C -> ``_two_level`` of [A, C, H = t - A]."""
     n1 = A_cum.shape[-1]
     H_cum = jnp.arange(n1, dtype=jnp.float32) * slot - A_cum
-    cum = jnp.stack([A_cum, C_cum, H_cum], axis=-2)
-    n_pad = -(-n1 // _CHUNK) * _CHUNK
-    widths = [(0, 0)] * (cum.ndim - 1) + [(0, n_pad - n1)]
-    return jnp.pad(cum, widths, constant_values=_BIG)
+    return _two_level(jnp.stack([A_cum, C_cum, H_cum], axis=-2))
 
 
 _TASK_KEYS = ("spot_cost", "ondemand_cost", "spot_work", "finish")
 _CHAIN_KEYS = ("spot_cost", "ondemand_cost", "spot_work", "ondemand_work")
 
 
-def _kernel(cum_ref, task_ref, out_ref, *, n_slots: int, slot: float,
-            p_od: float, BT: int):
+def _kernel(tiles_ref, heads_ref, task_ref, out_ref, *, n_slots: int,
+            slot: float, p_od: float, BT: int):
     start, end, z_t, d_eff = (to_col(task_ref[pl.ds(i, 1), :], BT)
                               for i in range(4))
-    r = _task_costs(cum_ref, start, end, z_t, d_eff, n_slots=n_slots,
-                    slot=slot, p_od=p_od, BT=BT)
+    r = _task_costs(tiles_ref, heads_ref, start, end, z_t, d_eff,
+                    n_slots=n_slots, slot=slot, p_od=p_od, BT=BT)
     for i, key in enumerate(_TASK_KEYS):
         out_ref[pl.ds(i, 1), :] = to_row(r[key], BT)
 
@@ -211,24 +256,25 @@ def policy_cost(A_cum, C_cum, start, end, z_t, d_eff, *,
     tasks = jnp.pad(jnp.stack([jnp.asarray(a, jnp.float32)
                                for a in (start, end, z_t, d_eff)]),
                     ((0, 0), (0, Tp - T)))
-    cum = _stack_cum(jnp.asarray(A_cum, jnp.float32),
-                     jnp.asarray(C_cum, jnp.float32), slot)
+    tiles, heads = _stack_cum(jnp.asarray(A_cum, jnp.float32),
+                              jnp.asarray(C_cum, jnp.float32), slot)
     kernel = functools.partial(_kernel, n_slots=n_slots, slot=slot,
                                p_od=p_od, BT=BT)
     out = pl.pallas_call(
         kernel,
         grid=(Tp // BT,),
-        in_specs=[pl.BlockSpec(cum.shape, lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec(tiles.shape, lambda i: (0, 0)),
+                  pl.BlockSpec(heads.shape, lambda i: (0, 0)),
                   pl.BlockSpec((4, BT), lambda i: (0, i))],
         out_specs=pl.BlockSpec((4, BT), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((4, Tp), jnp.float32),
         interpret=interpret,
-    )(cum, tasks)
+    )(tiles, heads, tasks)
     return {k: out[i, :T] for i, k in enumerate(_TASK_KEYS)}
 
 
-def _chain_kernel(cum_ref, arr_ref, ends_ref, z_ref, d_ref, pin_ref,
-                  out_ref, *, n_slots: int, L: int, slot: float,
+def _chain_kernel(tiles_ref, heads_ref, arr_ref, ends_ref, z_ref, d_ref,
+                  pin_ref, out_ref, *, n_slots: int, L: int, slot: float,
                   p_od: float, BT: int):
     def step(k, carry):
         cur, sc, oc, sw, ow = carry
@@ -242,8 +288,8 @@ def _chain_kernel(cum_ref, arr_ref, ends_ref, z_ref, d_ref, pin_ref,
         live = end > cur - 1e-15
         start = jnp.minimum(cur, end)
         z_t = jnp.where(live, z_raw, 0.0)
-        r = _task_costs(cum_ref, start, end, z_t, d_eff, n_slots=n_slots,
-                        slot=slot, p_od=p_od, BT=BT)
+        r = _task_costs(tiles_ref, heads_ref, start, end, z_t, d_eff,
+                        n_slots=n_slots, slot=slot, p_od=p_od, BT=BT)
         fin = jnp.where(pin, end, r["finish"])
         moved = (z_raw > 1e-15) | pin
         cur = jnp.where(moved, fin, cur)
@@ -306,7 +352,8 @@ def policy_cost_chain(A_cum, C_cum, arrival, ends, z_t, d_eff, pins, *,
         return jnp.swapaxes(a, 2, 3)
     ends_p, z_p, d_p, pins_p = map(to_lr, (ends, z_t, d_eff, pins))
     S_p = z_p.shape[1]
-    cum = _stack_cum(A_cum, C_cum, slot)                   # (B, S, 3, n_pad)
+    tiles, heads = _stack_cum(A_cum, C_cum, slot)  # (B, S, nt, 384),
+                                                   # (B, S, 3, nt)
 
     kernel = functools.partial(_chain_kernel, n_slots=n1 - 1, L=L,
                                slot=slot, p_od=p_od, BT=BT)
@@ -317,7 +364,9 @@ def policy_cost_chain(A_cum, C_cum, arrival, ends, z_t, d_eff, pins, *,
         kernel,
         grid=(B, S, (R + pt) // BT),
         in_specs=[
-            pl.BlockSpec((None, None) + cum.shape[2:],
+            pl.BlockSpec((None, None) + tiles.shape[2:],
+                         lambda b, s, i: (b, s, 0, 0)),
+            pl.BlockSpec((None, None) + heads.shape[2:],
                          lambda b, s, i: (b, s, 0, 0)),
             pl.BlockSpec((None, 1, BT), lambda b, s, i: (b, 0, i)),
             pl.BlockSpec((None, None, L, BT), lambda b, s, i: (b, 0, 0, i)),
@@ -329,7 +378,7 @@ def policy_cost_chain(A_cum, C_cum, arrival, ends, z_t, d_eff, pins, *,
                                lambda b, s, i: (b, s, 0, i)),
         out_shape=jax.ShapeDtypeStruct((B, S, 4, R + pt), jnp.float32),
         interpret=interpret,
-    )(cum, arrival, ends_p, z_p, d_p, pins_p)
+    )(tiles, heads, arrival, ends_p, z_p, d_p, pins_p)
     res = {k: out[:, :, i, :R] for i, k in enumerate(_CHAIN_KEYS)}
     if single_bid:
         res = {k: v[0] for k, v in res.items()}

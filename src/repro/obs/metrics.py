@@ -20,6 +20,9 @@ and learner record:
 * ``engine.delta_groups_rescored`` counter — eval groups actually
   re-scored by :func:`repro.engine.cache.evaluate_grid_delta` (the
   unchanged remainder was spliced from the previous result).
+* ``engine.eval.lookup_tiles`` gauge, label ``kernel=chain|task`` — the
+  lane tiles one slot lookup of a pallas cost-kernel launch reads (the
+  tile heads plus one tile), set from the static slot count.
 
 Per-chunk seconds are not a metric: they are the ``synth`` and ``eval``
 spans (and ``EngineResult.timings["chunks"]``).

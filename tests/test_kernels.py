@@ -121,3 +121,168 @@ class TestPolicyCostKernel:
                                    atol=2e-3, rtol=2e-3)
         np.testing.assert_allclose(out["ondemand_cost"], ref.ondemand_cost,
                                    atol=2e-3, rtol=2e-3)
+
+
+# --------------------------------------------------------------------------
+# The chain kernel's two-level slot lookup
+# --------------------------------------------------------------------------
+
+def _full_count(cum, targets):
+    """The full sweep's count #{k : cum[k] < target}, over every slot."""
+    return (np.asarray(cum)[None, :] < np.asarray(targets)[:, None]).sum(
+        axis=1)
+
+
+def _lookup(cum, targets, idx):
+    """The kernel's two-level counts of the three rows of ``cum`` (3, n1)
+    below ``targets``, and its gathers (A[k], C[k], A[k+1], C[k+1]) at
+    ``idx``, through one Pallas call (interpret mode)."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import policy_cost as pc
+
+    BT = 128
+    nq = -(-len(targets) // BT) * BT
+    tg = np.zeros(nq, np.float32)
+    tg[:len(targets)] = targets
+    ks = np.zeros(nq, np.int32)
+    ks[:len(idx)] = idx
+    tiles, heads = pc._two_level(jnp.asarray(cum, jnp.float32))
+
+    def kernel(t_ref, h_ref, q_ref, k_ref, o_ref):
+        q = pc.to_col(q_ref[...], BT)
+        k = pc.to_col(k_ref[...], BT)
+        vals = [pc._count(t_ref, h_ref, q, r, BT)[0].astype(jnp.float32)
+                for r in range(3)]
+        vals += pc._gather2(t_ref, h_ref, k, BT)
+        for i, v in enumerate(vals):
+            o_ref[pl.ds(i, 1), :] = pc.to_row(v, BT)
+
+    out = pl.pallas_call(
+        kernel, grid=(nq // BT,),
+        in_specs=[pl.BlockSpec(tiles.shape, lambda i: (0, 0)),
+                  pl.BlockSpec(heads.shape, lambda i: (0, 0)),
+                  pl.BlockSpec((1, BT), lambda i: (0, i)),
+                  pl.BlockSpec((1, BT), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((8, BT), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((8, nq), jnp.float32),
+        interpret=True)(tiles, heads, jnp.asarray(tg)[None],
+                        jnp.asarray(ks)[None])
+    out = np.asarray(out)
+    return (out[:3, :len(targets)].astype(np.int64),
+            out[3:7, :len(idx)])
+
+
+# n1 = n_slots + 1 slot boundaries: one tile, a full tile, one past it, a
+# ragged tile, and 130 tiles (the heads span two lane tiles).
+@pytest.mark.parametrize("n1", [5, 128, 129, 300, 128 * 129 + 2])
+def test_two_level_lookup_matches_full_sweep(n1):
+    """Count == searchsorted(side="left") and gather == indexing, bit for
+    bit, on nondecreasing f32 arrays with flat runs and ties."""
+    rng = np.random.default_rng(n1)
+    step = np.float32(1 / 12)
+    A = np.concatenate([[0], np.cumsum(rng.choice([0, step], n1 - 1))])
+    C = np.cumsum(rng.uniform(0, 3, n1))          # arbitrary mantissas
+    H = np.cumsum(rng.choice([0, step], n1))
+    cum = np.stack([A, C, H]).astype(np.float32)
+    vals = rng.choice(np.unique(cum), min(300, cum.size))
+    targets = np.concatenate([
+        vals,                                     # ties
+        np.nextafter(vals, np.float32(np.inf)),
+        np.nextafter(vals, np.float32(-np.inf)),
+        [-1.0, 0.0, cum.max() + 1, 1e30],         # below, above horizon
+    ]).astype(np.float32)
+    # The device flushes subnormals to zero, and no query target is one.
+    targets = targets[(targets == 0)
+                      | (np.abs(targets) >= np.finfo(np.float32).tiny)]
+    n = n1 - 1                                    # n_slots; k < n
+    idx = np.clip(np.concatenate([[0, 126, 127, 128, 129, 255, 256, n - 1],
+                                  rng.integers(0, n, 100)]), 0, n - 1)
+    counts, gathers = _lookup(cum, targets, idx)
+    for r in range(3):
+        np.testing.assert_array_equal(
+            counts[r], np.searchsorted(cum[r], targets, side="left"))
+        np.testing.assert_array_equal(counts[r], _full_count(cum[r], targets))
+    want = [A[idx], C[idx], A[idx + 1], C[idx + 1]]
+    for got, w in zip(gathers, want):
+        np.testing.assert_array_equal(got, np.asarray(w, np.float32))
+
+
+def _exp1_kernel_inputs(monkeypatch, n_jobs=24):
+    """The chain kernel's operands and outputs for one pallas
+    ``evaluate_grid`` call on an Experiment 1 stream (type-4 chains, the 25
+    spot/on-demand policies) against two fresh device-synthesized
+    markets."""
+    import repro.engine.backend_pallas as bp
+    from repro.core import generate_chain_jobs, spot_od_policies
+    from repro.engine import ScenarioSpec, evaluate_grid
+
+    jobs = generate_chain_jobs(n_jobs, job_type=4, seed=11)
+    spec = ScenarioSpec("fresh", max(j.deadline for j in jobs) + 1.0, 2,
+                        seed=1000)
+    chain, task = bp._kernels()
+    seen = {}
+
+    def spy(*args, **kw):
+        seen["args"], seen["out"] = args, chain(*args, **kw)
+        return seen["out"]
+
+    monkeypatch.setattr(bp, "_kernels", lambda: (spy, task))
+    evaluate_grid(jobs, spot_od_policies(), spec, 0, backend="pallas")
+    args = [np.asarray(a) for a in seen["args"]]
+    return args, {k: np.asarray(v) for k, v in seen["out"].items()}
+
+
+def test_chain_kernel_exp1_markets(monkeypatch):
+    """The chain kernel on Experiment 1 shapes against chain_costs_ref, and
+    the rows whose H counts (queried at the planned windows) the two-level
+    lookup answers differently from the full sweep: H = t - A is flat on
+    available slots but may wiggle by ulps in f32, the one place the two
+    can disagree; none does here."""
+    from repro.core.simulate import FLEX_ABS, FLEX_REL
+    from repro.kernels import policy_cost as pc
+    from repro.kernels.ref import chain_costs_ref
+
+    (A, C, arrival, ends, z_t, d_eff, pins), got = _exp1_kernel_inputs(
+        monkeypatch)
+    B, S, n1 = A.shape
+    slot = np.float32(1 / 12)
+    differ = 0
+    for b in range(B):
+        starts = np.concatenate([arrival[b][:, None], ends[b][:, :-1]], 1)
+        for s in range(S):
+            ref = chain_costs_ref(A[b, s], C[b, s], arrival[b], ends[b],
+                                  z_t[b], d_eff[b], pins[b])
+            # Per unit of work, as the engine's 1e-5 contract reads them.
+            wl = np.maximum(z_t[b].sum(axis=1), 1e-12)
+            for pair in (("spot_cost", "ondemand_cost"),
+                         ("spot_work", "ondemand_work")):
+                np.testing.assert_allclose(
+                    sum(got[k][b, s] for k in pair) / wl,
+                    sum(np.asarray(ref[k]) for k in pair) / wl,
+                    atol=1e-5, rtol=1e-5, err_msg=f"{pair} bid {b} s {s}")
+            np.testing.assert_allclose(
+                got["spot_work"][b, s] / wl, np.asarray(ref["spot_work"]) / wl,
+                atol=1e-5, rtol=1e-5, err_msg=f"spot share bid {b} s {s}")
+            # H as the kernel holds it, and its targets at the planned
+            # windows, formed as passes 1 and 2 of _task_costs form them.
+            tiles = np.asarray(pc._stack_cum(A[b, s], C[b, s], 1 / 12)[0])
+            H = tiles[:, 2 * 128:].reshape(-1)[:n1]
+            k0 = np.clip((starts / slot).astype(np.int32), 0, n1 - 2)
+            A0 = A[b, s][k0] + (A[b, s][k0 + 1] - A[b, s][k0]) / slot * (
+                starts - k0 * slot)
+            need = z_t[b] / np.where(d_eff[b] > 0, d_eff[b], 1)
+            tgt = (starts - A0 + (ends[b] - starts) - need).astype(
+                np.float32)
+            two, _ = _lookup(np.stack([A[b, s], C[b, s], H]), tgt.ravel(),
+                             np.zeros(1, np.int32))
+            # Only the counts of active tasks with slack reach a result.
+            span = ends[b] - starts
+            no_flex = span - need <= np.maximum(
+                1e-15, np.maximum(FLEX_REL * span, FLEX_ABS * ends[b]))
+            used = (z_t[b] > 1e-15) & ~no_flex
+            diff = two[2].reshape(tgt.shape) != _full_count(
+                H, tgt.ravel()).reshape(tgt.shape)
+            differ += int((diff & used).any(axis=1).sum())
+    assert differ == 0

@@ -393,6 +393,28 @@ def test_adaptive_escalation_counter():
     assert "learn.top_weight" in m
 
 
+@pytest.mark.parametrize("kernel, kw", [
+    ("chain", {}),
+    ("task", dict(windows="even", selfowned="naive", early_start=False,
+                  pool="shared")),
+])
+def test_pallas_lookup_tiles_gauge(kernel, kw):
+    """Each pallas launch records the lane tiles one slot lookup reads —
+    the tile heads, plus one tile — only while metrics are collected."""
+    pytest.importorskip("jax")
+    jobs, horizon = _setup()
+    spec = ScenarioSpec("fresh", horizon, 2, seed=6)
+    METRICS.reset()
+    evaluate_grid(jobs, GRID, spec, backend="pallas", **kw)
+    assert "engine.eval.lookup_tiles" not in METRICS.snapshot()
+    with METRICS.collecting(reset=True):
+        res = evaluate_grid(jobs, GRID, spec, backend="pallas", **kw)
+    tiles = -(-(spec.n_slots + 1) // 128)
+    heads = -(-tiles // 128)
+    series = res.obs["metrics"]["engine.eval.lookup_tiles"]["series"]
+    assert series == [{"labels": {"kernel": kernel}, "value": heads + 1}]
+
+
 # --------------------------------------------------------------------------
 # Compiled-program introspection
 # --------------------------------------------------------------------------
