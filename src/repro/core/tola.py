@@ -24,6 +24,12 @@ Implementation notes (faithful, but vectorized):
   losses in [0, p_od], as the regret bound of Prop. B.1 assumes.
 * The realized pass replays the sampled policies chronologically against the
   shared self-owned pool (same plan machinery as ``run_jobs``).
+
+Round 0's engine call runs inside the span ``tola.score`` and each pool
+refinement's inside ``tola.rescore`` (attribute ``round``). Inside
+``METRICS.collecting()`` every round sets the gauge
+``tola.selfowned_share{round}``: the share of the stream's work the realized
+run did on self-owned instances, averaged over the markets.
 """
 
 from __future__ import annotations
@@ -41,7 +47,7 @@ from repro.core.scheduler import (
     build_plans,
 )
 from repro.core.types import ChainJob
-from repro.obs import span
+from repro.obs import METRICS, span
 
 __all__ = ["TolaResult", "cost_matrix", "run_tola", "run_tola_scenarios"]
 
@@ -92,6 +98,23 @@ def cost_matrix(
         selfowned=selfowned, early_start=early_start,
         availability=availability, pool="dedicated", backend=backend)
     return res.matrix
+
+
+def _engine_span(round_: int):
+    """The span around one round's engine call: ``tola.score`` for round 0,
+    ``tola.rescore`` for each pool refinement."""
+    if round_ == 0:
+        return span("tola.score")
+    return span("tola.rescore", round=round_)
+
+
+def _record_selfowned_share(realized: list[StreamCosts], round_: int) -> None:
+    """Gauge ``tola.selfowned_share{round}``: realized self-owned share of
+    the stream's work, averaged over the markets."""
+    if METRICS.enabled:
+        share = np.mean([r.selfowned_work.sum() / r.workload.sum()
+                         for r in realized])
+        METRICS.gauge("tola.selfowned_share").set(share, round=round_)
 
 
 def _residual_availability(pool, r_total: int, slot: float):
@@ -189,11 +212,14 @@ def run_tola(
             if it == 0 and _C0 is not None:
                 C = _C0
             else:
-                C = cost_matrix(jobs, policies, market, r_total, windows,
-                                selfowned, early_start, availability, backend)
+                with _engine_span(it):
+                    C = cost_matrix(jobs, policies, market, r_total, windows,
+                                    selfowned, early_start, availability,
+                                    backend)
             lr, chosen, realized, availability = _tola_round(
                 jobs, policies, C, arrivals, d, Z, spec, rng, market,
                 r_total, windows, selfowned, early_start, round_=it)
+            _record_selfowned_share([realized], it)
 
     fixed = (C * Z[:, None]).sum(axis=0) / Z.sum()
     return TolaResult(chosen=chosen, weights=lr.weights[0, 0],
@@ -247,11 +273,12 @@ def run_tola_scenarios(
     iters = 1 + (pool_iters if r_total > 0 else 0)
     with span("tola"):
         for it in range(iters):
-            res = evaluate_grid(
-                jobs, policies, markets, r_total, windows=windows,
-                selfowned=selfowned, early_start=early_start,
-                pool="dedicated", availability=avails, backend=backend,
-                mesh=mesh)
+            with _engine_span(it):
+                res = evaluate_grid(
+                    jobs, policies, markets, r_total, windows=windows,
+                    selfowned=selfowned, early_start=early_start,
+                    pool="dedicated", availability=avails, backend=backend,
+                    mesh=mesh)
             C = res.unit_cost
             rounds = [
                 _tola_round(jobs, policies, C[s], arrivals, d, Z, spec,
@@ -259,6 +286,7 @@ def run_tola_scenarios(
                             early_start, round_=it, scenario=s)
                 for s in range(S)
             ]
+            _record_selfowned_share([r[2] for r in rounds], it)
             avails = [r[3] for r in rounds]
             if any(a is None for a in avails):
                 avails = None  # r_total == 0: nothing to refine against

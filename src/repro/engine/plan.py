@@ -35,6 +35,9 @@ self-owned arrays gain a leading scenario axis — groups carry (S, J, L)
 tensors and backends pair scenario s with slice s. Availability queries
 are host callables, so the device path stages the planned windows to host
 once to evaluate them (the default query-free path never leaves device).
+Inside ``METRICS.collecting()`` the counter
+``engine.plan.availability_windows`` counts the (start, end) windows a
+refined plan sends to its availability queries, padding tasks included.
 """
 
 from __future__ import annotations
@@ -44,7 +47,7 @@ import functools
 
 import numpy as np
 
-from repro.obs import record_jit, span
+from repro.obs import METRICS, record_jit, span
 
 from repro.engine import cache as _cache
 from repro.core.scheduler import (
@@ -81,6 +84,14 @@ def _xp_of(a):
     import jax.numpy as jnp
 
     return jnp
+
+
+def _query(q, starts: np.ndarray, ends: np.ndarray) -> np.ndarray:
+    """One availability query over planned windows, counted in
+    ``engine.plan.availability_windows``."""
+    if METRICS.enabled:
+        METRICS.counter("engine.plan.availability_windows").inc(starts.size)
+    return q(starts, ends)
 
 
 def concat_rows(arrays):
@@ -430,9 +441,10 @@ def _group_alloc(plan: PlanBatch, pol_beta0: float | None, r_total: int,
         avail = float(r_total)
     elif isinstance(availability, (list, tuple)):
         # Per-scenario residual-occupancy queries -> (S, J, L) availability.
-        avail = np.stack([q(plan.starts, plan.ends) for q in availability])
+        avail = np.stack([_query(q, plan.starts, plan.ends)
+                          for q in availability])
     else:
-        avail = availability(plan.starts, plan.ends)
+        avail = _query(availability, plan.starts, plan.ends)
     r_alloc = _selfowned_counts_vec(
         plan.z, plan.delta, plan.sizes, beta0[:, None], avail, selfowned)
     return np.where(plan.mask, r_alloc, 0.0)
@@ -553,11 +565,11 @@ def _build_grid_plan_device(jobs, policies, s: _GridStructure, arrays,
     with span("pool", plan_backend="device") as sp:
         h_starts, h_ends = np.asarray(starts), np.asarray(ends)
         if isinstance(availability, (list, tuple)):
-            avail = np.stack([[q(h_starts[p], h_ends[p])
+            avail = np.stack([[_query(q, h_starts[p], h_ends[p])
                                for q in availability]
                               for p in plan_of_akey])
         else:
-            avail = np.stack([availability(h_starts[p], h_ends[p])
+            avail = np.stack([_query(availability, h_starts[p], h_ends[p])
                               for p in plan_of_akey])
         group_args = (arrays.z, arrays.delta, arrays.mask, sizes,
                       plan_of_akey, b0, jnp.asarray(avail),

@@ -23,6 +23,12 @@ and learner record:
 * ``engine.eval.lookup_tiles`` gauge, label ``kernel=chain|task`` — the
   lane tiles one slot lookup of a pallas cost-kernel launch reads (the
   tile heads plus one tile), set from the static slot count.
+* ``engine.plan.availability_windows`` counter — the (start, end) windows
+  a refined grid plan sends to its availability queries (TOLA's pool
+  refinement), padding tasks included.
+* ``tola.selfowned_share`` gauge, label ``round`` — the share of the
+  stream's work a TOLA round's realized run did on self-owned instances,
+  averaged over the markets.
 
 Per-chunk seconds are not a metric: they are the ``synth`` and ``eval``
 spans (and ``EngineResult.timings["chunks"]``).

@@ -656,7 +656,13 @@ def test_tola_span_tree():
         assert r.parent == root.id
         assert _kids(tr, r) == ["replay", "tola.availability", "tola.plans",
                                 "tola.pool", "tola.realize"]
-    assert [r.parent for r in tr.named("evaluate_grid")] == [root.id] * 2
+    # Each round's engine call sits in its own span under the root.
+    (score,) = tr.named("tola.score")
+    (rescore,) = tr.named("tola.rescore")
+    assert score.parent == rescore.parent == root.id
+    assert rescore.attrs["round"] == 1
+    assert [r.parent for r in tr.named("evaluate_grid")] == [score.id,
+                                                             rescore.id]
 
 
 def test_single_market_tola_has_one_root():
