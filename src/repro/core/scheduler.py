@@ -161,11 +161,13 @@ class JobArrays:
     """Padded per-job task arrays — the policy-independent half of a plan.
 
     Extracted ONCE per job stream (one cheap padding pass) and shared by
-    every window plan of a grid; ``omega`` is the Dealloc slack
-    ``window - e.sum()`` and ``slack_even`` the Even-benchmark slack
-    (``job.slack``, a Python-sum of e_i) — kept separate because the two
-    sequential paths reduce e differently and bit-compatibility requires
-    reproducing each exactly.
+    every window plan of a grid; ``window`` is each job's ``d_j - a_j``,
+    ``omega`` the Dealloc slack ``window - e.sum()`` and ``slack_even`` the
+    Even-benchmark slack (``job.slack``, a Python-sum of e_i) — kept
+    separate because the two sequential paths reduce e differently and
+    bit-compatibility requires reproducing each exactly. The array fields
+    determine every plan built from them, so they alone make the plan
+    cache's content key (``engine.cache.fingerprint_job_arrays``).
     """
 
     arrival: np.ndarray   # (J,)
@@ -175,6 +177,7 @@ class JobArrays:
     mask: np.ndarray      # (J, L) real-task mask
     omega: np.ndarray     # (J,) Dealloc slack
     l: np.ndarray         # (J,) chain lengths
+    window: np.ndarray    # (J,) d_j - a_j
     jobs: list[ChainJob] | None = None  # source stream (Even-slack fallback)
 
     def slack_even(self) -> np.ndarray:
@@ -211,7 +214,7 @@ def job_arrays(jobs: list[ChainJob]) -> JobArrays:
     omega = np.array([window[ji] - float(flat_e[off[ji]:off[ji + 1]].sum())
                       for ji in range(J)])
     return JobArrays(arrival=arrival, z=z, delta=delta, e=e, mask=mask,
-                     omega=omega, l=ls, jobs=jobs)
+                     omega=omega, l=ls, window=window, jobs=jobs)
 
 
 def _plans_from_sizes(arrays: JobArrays, sizes: np.ndarray) -> list[PlanBatch]:

@@ -198,10 +198,14 @@ def plan_cache_events(hits: int = 0, misses: int = 0) -> None:
 
 def _hash_arrays(h, arrays) -> None:
     for f in dataclasses.fields(arrays):
+        # ``jobs`` is the source list the arrays were read from, not a plan
+        # input: its repr walks every Task (MBs per call at §6.1 sizes).
+        if f.name == "jobs":
+            continue
         v = getattr(arrays, f.name)
         h.update(f.name.encode())
         if isinstance(v, np.ndarray):
-            h.update(str(v.dtype).encode())
+            h.update(f"{v.dtype}{v.shape}".encode())
             h.update(np.ascontiguousarray(v).tobytes())
         else:
             h.update(repr(v).encode())
@@ -209,7 +213,13 @@ def _hash_arrays(h, arrays) -> None:
 
 def fingerprint_job_arrays(arrays) -> str:
     """Content hash of a ``JobArrays`` batch — every field the plan layer
-    reads, so any change to the job set invalidates its cache entries."""
+    reads, so any change to the job set invalidates its cache entries.
+
+    The key is the arrays' dtypes, shapes and bytes; the ``jobs``
+    back-reference is skipped. ``arrival``, ``window``, ``z``, ``delta``,
+    ``mask`` and ``l`` determine both slacks (``omega`` and
+    ``slack_even``), so equal keys give bitwise-equal plans in either
+    window mode."""
     h = hashlib.sha1()
     _hash_arrays(h, arrays)
     return h.hexdigest()

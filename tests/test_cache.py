@@ -8,7 +8,14 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import SpotMarket, generate_chain_jobs, selfowned_policies
+from repro.core import (
+    ChainJob,
+    SpotMarket,
+    Task,
+    generate_chain_jobs,
+    selfowned_policies,
+)
+from repro.core.scheduler import JobArrays, job_arrays
 from repro.engine import (
     available_backends,
     evaluate_grid,
@@ -275,6 +282,119 @@ def test_jobs_fingerprint_invalidates():
     assert res2.timings["plan_cached"] == 0       # different jobs: all miss
     assert h1.hits == h0.hits
     assert cache.jobs_fingerprint(jobs) != cache.jobs_fingerprint(jobs2)
+
+
+def _with_task(job, k, **fields):
+    tasks = list(job.tasks)
+    tasks[k] = dataclasses.replace(tasks[k], **fields)
+    return dataclasses.replace(job, tasks=tuple(tasks))
+
+
+def _edit(jobs, ji, fn):
+    jobs = list(jobs)
+    jobs[ji] = fn(jobs[ji])
+    return jobs
+
+
+# One edit each to a quantity the plan reads, on a job of 7 tasks.
+JOB_EDITS = {
+    "deadline": lambda jobs: _edit(
+        jobs, 2, lambda j: dataclasses.replace(j, deadline=j.deadline + 5.0)),
+    "task_z": lambda jobs: _edit(
+        jobs, 2, lambda j: _with_task(j, 1, z=j.tasks[1].z * 0.5)),
+    "task_delta": lambda jobs: _edit(
+        jobs, 2, lambda j: _with_task(j, 1, delta=j.tasks[1].delta * 2)),
+    "job_order": lambda jobs: jobs[:2] + [jobs[3], jobs[2]] + jobs[4:],
+    "task_count": lambda jobs: _edit(
+        jobs, 2, lambda j: dataclasses.replace(j, tasks=j.tasks[:-1])),
+}
+
+WINDOW_CFGS = {
+    "dealloc": dict(r_total=600),
+    "even": dict(r_total=600, windows="even", selfowned="naive",
+                 pool="shared"),
+}
+
+
+@pytest.mark.parametrize("windows", list(WINDOW_CFGS))
+@pytest.mark.parametrize("edit", list(JOB_EDITS))
+def test_fingerprint_covers_every_plan_input(edit, windows):
+    """Any one edit to a job quantity the plan reads changes the key: the
+    next call misses every group and equals a cache-off run bitwise."""
+    jobs, markets = _setup()
+    kw = dict(WINDOW_CFGS[windows], backend="numpy")
+    evaluate_grid(jobs, _grid(), markets, **kw)
+    edited = JOB_EDITS[edit](jobs)
+    assert cache.jobs_fingerprint(edited) != cache.jobs_fingerprint(jobs)
+    misses = cache.PLAN_CACHE.cache_info().misses
+    warm = evaluate_grid(edited, _grid(), markets, **kw)
+    assert warm.timings["plan_cached"] == 0
+    assert (cache.PLAN_CACHE.cache_info().misses - misses
+            == len(warm.delta_state["group_rep"]))
+    with cache.disabled():
+        off = evaluate_grid(edited, _grid(), markets, **kw)
+    _assert_bitwise(warm, off)
+
+
+@pytest.mark.parametrize("windows", list(WINDOW_CFGS))
+def test_fingerprint_rebuilt_equal_jobs_hit(windows):
+    """New ChainJob/Task objects with equal content key the same: the call
+    on them hits every group and returns the same tensors bitwise."""
+    jobs, markets = _setup()
+    kw = dict(WINDOW_CFGS[windows], backend="numpy")
+    cold = evaluate_grid(jobs, _grid(), markets, **kw)
+    rebuilt = [ChainJob(j.arrival, j.deadline,
+                        tuple(Task(t.z, t.delta) for t in j.tasks))
+               for j in jobs]
+    assert all(a is not b for a, b in zip(jobs, rebuilt))
+    assert cache.jobs_fingerprint(rebuilt) == cache.jobs_fingerprint(jobs)
+    misses = cache.PLAN_CACHE.cache_info().misses
+    warm = evaluate_grid(rebuilt, _grid(), markets, **kw)
+    assert cache.PLAN_CACHE.cache_info().misses == misses
+    assert warm.timings["plan_cached"] == len(cache.PLAN_CACHE)
+    _assert_bitwise(cold, warm)
+
+
+def test_fingerprint_never_walks_job_objects(monkeypatch):
+    """The key is the arrays' bytes: it never reprs a job or a task, and
+    the ``jobs`` back-reference does not enter it."""
+    jobs, _ = _setup()
+    arrays = job_arrays(jobs)
+
+    def _no_repr(self):
+        raise AssertionError("the fingerprint walked a job object")
+
+    monkeypatch.setattr(ChainJob, "__repr__", _no_repr)
+    monkeypatch.setattr(Task, "__repr__", _no_repr)
+    fp = cache.fingerprint_job_arrays(arrays)
+    assert fp == cache.fingerprint_job_arrays(
+        dataclasses.replace(arrays, jobs=None))
+    assert fp == cache.jobs_fingerprint(jobs)
+
+
+def test_fingerprint_hashes_shapes():
+    """Equal bytes under another shape key differently."""
+    jobs, _ = _setup()
+    arrays = job_arrays(jobs)
+    J, L = arrays.z.shape
+    assert J != L
+    reshaped = dataclasses.replace(arrays, z=arrays.z.reshape(L, J))
+    assert reshaped.z.tobytes() == arrays.z.tobytes()
+    assert (cache.fingerprint_job_arrays(reshaped)
+            != cache.fingerprint_job_arrays(arrays))
+
+
+@pytest.mark.parametrize(
+    "field", [f.name for f in dataclasses.fields(JobArrays) if f.name != "jobs"])
+def test_fingerprint_hashes_every_array_field(field):
+    """One element changed in any one array field changes the key."""
+    jobs, _ = _setup()
+    arrays = job_arrays(jobs)
+    v = getattr(arrays, field).copy()
+    v.flat[0] = not v.flat[0] if v.dtype == bool else v.flat[0] + 1
+    edited = dataclasses.replace(arrays, **{field: v})
+    assert (cache.fingerprint_job_arrays(edited)
+            != cache.fingerprint_job_arrays(arrays))
 
 
 def test_scenario_fingerprint_kinds():
