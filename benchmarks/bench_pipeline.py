@@ -57,8 +57,9 @@ per-scenario-availability path of DESIGN.md §9):
 
 * ``jax+refine`` — ``run_tola_scenarios`` with ``pool_iters`` refinement
   rounds, ONE batched per-scenario-availability engine pass per round,
-  raced against the per-scenario ``run_tola`` loop it replaced (one
-  engine call per scenario per round, same results to f32 tolerance);
+  raced against a loop of single-market (S = 1) ``run_tola_scenarios``
+  calls (one engine call per scenario per round, same results to f32
+  tolerance);
   ``refine_batch_speedup`` is a same-machine ratio with a modest CI
   floor — the engine pass batches but the learner replay between rounds
   is identical host work in both paths, so Amdahl caps the end-to-end
@@ -495,13 +496,13 @@ def _shard_section(out, jobs, grid, stream_leg, mesh, shard_scale_max,
     sharded fold — wall clock grows linearly in S while peak memory stays
     pinned at one chunk.
     """
-    from repro.engine import ScenarioMesh
+    from repro.engine import GridMesh
     from repro.engine.mesh import as_scenario_mesh
     from repro.learn import replay_stream
 
     smesh = as_scenario_mesh(mesh)
     if smesh is None:
-        smesh = ScenarioMesh.create()
+        smesh = GridMesh.create()
     plain = stream_leg("jax+shard", "jax", smesh=smesh, overlap=False)
     over = stream_leg("jax+shard+overlap", "jax", smesh=smesh, overlap=True)
     # The overlap win: residual synth wait once chunk k+1 is dispatched
@@ -560,9 +561,9 @@ def _refine_section(out, jobs, grid, markets, r_total, seed, pool_iters,
     """TOLA pool-refinement legs: per-scenario loop vs batched vs sharded.
 
     ``run_tola_scenarios`` makes exactly ONE per-scenario-availability
-    engine pass per refinement round; the loop baseline is the
-    ``run_tola``-per-market path it replaced (one engine call per
-    scenario per round, same results to f32 tolerance).
+    engine pass per refinement round; the loop baseline calls it once per
+    market at S = 1 (one engine call per scenario per round, same results
+    to f32 tolerance).
     ``refine_batch_speedup`` is a same-machine ratio with a modest CI
     floor (the per-round learner replay is identical host work in both
     paths, so Amdahl caps the end-to-end ratio). The sharded leg rides
@@ -573,18 +574,21 @@ def _refine_section(out, jobs, grid, markets, r_total, seed, pool_iters,
     visible cores, so the absolute shard win needs real multi-device
     hardware.
     """
-    from repro.core import run_tola, run_tola_scenarios
+    from repro.core import run_tola_scenarios
 
     S = len(markets)
     kw = dict(r_total=r_total, pool_iters=pool_iters, backend="jax")
     rounds = 1 + pool_iters
     cells = S * len(jobs) * len(grid) * rounds
 
-    def loop():
-        return [run_tola(jobs, grid, markets[s], seed=seed + s, **kw)
-                for s in range(S)]
+    def solo(s):
+        return run_tola_scenarios(jobs, grid, [markets[s]], seed=seed + s,
+                                  **kw)[0]
 
-    run_tola(jobs, grid, markets[0], seed=seed, **kw)  # absorb S=1 compiles
+    def loop():
+        return [solo(s) for s in range(S)]
+
+    solo(0)  # absorb S=1 compiles
     t0 = time.perf_counter()
     res_loop = loop()
     t_loop = time.perf_counter() - t0

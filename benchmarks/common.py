@@ -41,7 +41,7 @@ class Setup:
         self.seed = seed
         self.backend = backend
         self.scenario_chunk = scenario_chunk
-        self.mesh = mesh                # ScenarioMesh | int | None
+        self.mesh = mesh                # GridMesh | int | None
         self._source = as_source(scenarios)
 
     @property

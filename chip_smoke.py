@@ -195,8 +195,7 @@ def pallas_phase(w: Workload, ref):
 
     with phase("pallas") as info:
         with capture() as reg:
-            res = w.evaluate(S_PALLAS, backend="pallas",
-                             interpret=PLATFORM == "cpu")
+            res = w.evaluate(S_PALLAS, backend="pallas")
         kernel = reg.entries["engine.eval.pallas_chain"]
         need("error" not in kernel, kernel)
         diff = compare(res, ref)
@@ -240,8 +239,7 @@ def tola_phase(w: Workload):
         Z = np.array([j.total_work for j in w.jobs])
         ref = replay(C, arrivals, d, workload=Z, seed=7, backend="numpy")
         for backend in ("jax", "pallas"):
-            got = replay(C, arrivals, d, workload=Z, seed=7, backend=backend,
-                         interpret=PLATFORM == "cpu")
+            got = replay(C, arrivals, d, workload=Z, seed=7, backend=backend)
             agree = float((got.chosen == ref.chosen).mean())
             wdiff = float(np.abs(got.weights - ref.weights).max())
             info[f"{backend}_trace_agree"] = agree

@@ -19,7 +19,7 @@ from repro.core import (
     generate_chain_jobs,
     run_greedy,
     run_jobs,
-    run_tola,
+    run_tola_scenarios,
     spot_od_policies,
     window_sizes,
 )
@@ -46,7 +46,7 @@ print(f"cost improvement: {1 - best / greedy:.2%} vs greedy, "
       f"{1 - best / even:.2%} vs even")
 
 # --- 3. online learning (TOLA) over the policy grid -------------------------
-res = run_tola(jobs, spot_od_policies(), market, seed=0)
+res = run_tola_scenarios(jobs, spot_od_policies(), [market], seed=0)[0]
 print(f"TOLA realized alpha {res.average_unit_cost():.4f}, "
       f"best fixed {res.best_fixed_unit_cost:.4f}, "
       f"top policy weight {res.weights.max():.3f}")

@@ -426,10 +426,10 @@ def program_inventory(mesh=None, keys: Sequence[str] | None = None
     8 host devices so the sharded programs verify with real cross-device
     axes, including 4x2/2x4 grids.)
     """
-    from repro.engine import ScenarioMesh
+    from repro.engine import GridMesh
 
     if mesh is None:
-        mesh = ScenarioMesh.create()
+        mesh = GridMesh.create()
     builders = (
         lambda: _build_eval_programs(mesh),
         lambda: _build_scenario_programs(mesh),

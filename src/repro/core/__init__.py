@@ -31,7 +31,7 @@ from repro.core.scheduler import (
     run_jobs,
 )
 from repro.core.simulate import simulate_tasks
-from repro.core.tola import cost_matrix, run_tola, run_tola_scenarios
+from repro.core.tola import run_tola_scenarios
 from repro.core.transform import chain_of, transform
 from repro.core.types import Allocation, ChainJob, DAGJob, Task, chain_from_arrays
 from repro.core.workload import generate_chain_jobs, generate_dag_jobs
@@ -43,7 +43,7 @@ __all__ = [
     "build_plans_batch", "job_arrays", "LazySegmentTree",
     "f_selfowned", "selfowned_allocation", "spot_ondemand_split",
     "simulate_tasks", "run_jobs", "evaluate_policy_fullpool",
-    "run_tola", "run_tola_scenarios", "cost_matrix", "transform", "chain_of",
+    "run_tola_scenarios", "transform", "chain_of",
     "generate_chain_jobs", "generate_dag_jobs",
     "spot_od_policies", "selfowned_policies", "benchmark_bid_policies",
     "run_greedy", "run_even", "sweep_policies", "C1_BETA0", "C2_BETA",

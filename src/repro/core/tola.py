@@ -49,7 +49,7 @@ from repro.core.scheduler import (
 from repro.core.types import ChainJob
 from repro.obs import METRICS, span
 
-__all__ = ["TolaResult", "cost_matrix", "run_tola", "run_tola_scenarios"]
+__all__ = ["TolaResult", "run_tola_scenarios"]
 
 
 @dataclasses.dataclass
@@ -72,32 +72,6 @@ class TolaResult:
     def regret_per_job(self) -> float:
         """Realized average excess unit cost vs the best fixed policy."""
         return self.average_unit_cost() - self.best_fixed_unit_cost
-
-
-def cost_matrix(
-    jobs: list[ChainJob],
-    policies: list[Policy],
-    market: SpotMarket,
-    r_total: int = 0,
-    windows: str = "dealloc",
-    selfowned: str = "prop12",
-    early_start: bool = True,
-    availability=None,
-    backend: str = "auto",
-) -> np.ndarray:
-    """C[j, pi] — per-unit-workload counterfactual cost of job j under pi.
-
-    Routed through the batched evaluation engine: the whole grid is one
-    ``evaluate_grid`` call (deduplicated policy groups, backend-dispatched to
-    numpy / jax / the pallas kernel — see ``repro.engine``).
-    """
-    from repro.engine import evaluate_grid  # engine depends on core
-
-    res = evaluate_grid(
-        jobs, policies, market, r_total, windows=windows,
-        selfowned=selfowned, early_start=early_start,
-        availability=availability, pool="dedicated", backend=backend)
-    return res.matrix
 
 
 def _engine_span(round_: int):
@@ -167,66 +141,6 @@ def _tola_round(jobs, policies, C, arrivals, d, Z, spec, rng, market,
     return lr, chosen, realized, availability
 
 
-def run_tola(
-    jobs: list[ChainJob],
-    policies: list[Policy],
-    market: SpotMarket,
-    r_total: int = 0,
-    seed: int = 0,
-    windows: str = "dealloc",
-    selfowned: str = "prop12",
-    early_start: bool = True,
-    pool_iters: int = 1,
-    backend: str = "auto",
-    learner="hedge",
-    _C0: np.ndarray | None = None,
-) -> TolaResult:
-    """Full Algorithm 4 over an arrival-ordered job list.
-
-    ``pool_iters``: number of pool-aware refinements of the counterfactual
-    cost matrix. Iteration 0 scores policies against a dedicated pool (the
-    [10]/[12] simplification); each refinement re-scores them against the
-    residual availability realized by the previous iteration's run — without
-    this, the learner never sees self-owned scarcity and over-rewards
-    pool-hogging (small beta_0) policies.
-
-    ``backend`` selects the engine backend for the cost-matrix evaluations
-    (the learner replay itself always runs the float64 numpy oracle of
-    ``repro.learn`` — Hedge there is bit-compatible with the original
-    in-module loop); ``learner`` is a kind name or ``LearnerSpec`` from
-    ``repro.learn.learners``. ``_C0`` optionally injects a precomputed
-    iteration-0 matrix (used to share a batched engine pass).
-    """
-    from repro.learn import as_spec
-
-    if not jobs or not policies:
-        raise ValueError("need jobs and policies")
-    arrivals, d, Z = _stream_meta(jobs)
-    spec = as_spec(learner)
-    rng = np.random.default_rng(seed)
-
-    availability = None
-    iters = 1 + (pool_iters if r_total > 0 else 0)
-    with span("tola"):
-        for it in range(iters):
-            if it == 0 and _C0 is not None:
-                C = _C0
-            else:
-                with _engine_span(it):
-                    C = cost_matrix(jobs, policies, market, r_total, windows,
-                                    selfowned, early_start, availability,
-                                    backend)
-            lr, chosen, realized, availability = _tola_round(
-                jobs, policies, C, arrivals, d, Z, spec, rng, market,
-                r_total, windows, selfowned, early_start, round_=it)
-            _record_selfowned_share([realized], it)
-
-    fixed = (C * Z[:, None]).sum(axis=0) / Z.sum()
-    return TolaResult(chosen=chosen, weights=lr.weights[0, 0],
-                      realized=realized, cost_matrix=C,
-                      fixed_unit_costs=fixed, learn=lr)
-
-
 def run_tola_scenarios(
     jobs: list[ChainJob],
     policies: list[Policy],
@@ -241,16 +155,27 @@ def run_tola_scenarios(
     learner="hedge",
     mesh=None,
 ) -> list[TolaResult]:
-    """Algorithm 4 across S market scenarios, cost matrices batched.
+    """Full Algorithm 4 over an arrival-ordered job list, in each of S
+    market scenarios, cost matrices batched. One market is ``[market]``:
+    ``run_tola_scenarios(jobs, policies, [market], ...)[0]``.
 
-    Exactly ONE ``evaluate_grid`` call per refinement round, covering every
-    scenario: round 0 is the engine's ordinary scenario axis; each pool
-    refinement re-scores the grid against the S realized residual-
-    availability queries in a single per-scenario-availability pass (the
-    engine stacks the refined plan tensors along the scenario axis).
-    The sequential sample/update replay runs per scenario with seed
-    ``seed + s`` — bit-identical to looping single-market ``run_tola``
-    (Table 6 output included), just without the per-scenario engine calls.
+    ``pool_iters``: number of pool-aware refinements of the counterfactual
+    cost matrix. Round 0 scores policies against a dedicated pool (the
+    [10]/[12] simplification); each refinement re-scores them against the
+    residual availability realized by the previous round's run — without
+    this, the learner never sees self-owned scarcity and over-rewards
+    pool-hogging (small beta_0) policies.
+
+    Exactly ONE ``evaluate_grid`` call per round, covering every scenario:
+    round 0 is the engine's ordinary scenario axis; each pool refinement
+    re-scores the grid against the S realized residual-availability
+    queries in a single per-scenario-availability pass (the engine stacks
+    the refined plan tensors along the scenario axis). ``backend`` selects
+    the engine backend; the sequential sample/update replay always runs
+    the float64 numpy oracle of ``repro.learn`` per scenario, with seed
+    ``seed + s`` (Hedge there is bit-compatible with the original
+    in-module loop, Table 6 output included). ``learner`` is a kind name
+    or ``LearnerSpec`` from ``repro.learn.learners``.
 
     ``mesh`` shards the scenario axis across a device mesh (DESIGN.md §9)
     in EVERY round: round 0 shards the ordinary scenario axis, and the

@@ -35,7 +35,7 @@ from repro.engine.cache import (
     setup_persistent_cache,
 )
 from repro.engine.cache import configure as configure_caches
-from repro.engine.mesh import GridMesh, ScenarioMesh, as_scenario_mesh
+from repro.engine.mesh import GridMesh, as_scenario_mesh
 from repro.engine.plan import EvalGroup, GridPlan, build_grid_plan
 from repro.engine.result import EngineResult
 from repro.engine.scenarios import (
@@ -56,7 +56,7 @@ __all__ = [
     "evaluate_grid_delta", "clear_caches", "configure_caches",
     "jobs_fingerprint", "scenario_fingerprint", "setup_persistent_cache",
     "EngineResult", "EvalGroup", "GridPlan", "build_grid_plan",
-    "GridMesh", "ScenarioMesh", "as_scenario_mesh",
+    "GridMesh", "as_scenario_mesh",
     "ScenarioSpec", "ScenarioStream", "ScenarioBatch", "as_source",
     "make_scenarios", "adversarial_scenarios", "replay_scenarios",
     "check_scenarios", "stack_views",

@@ -195,7 +195,7 @@ def _prepare_stream(jobs, policies, scenarios, r_total, windows, selfowned,
         if backend != "jax":
             raise ValueError(
                 f"mesh= shards the scenario axis of the jax backend; "
-                f"backend {backend!r} cannot consume a ScenarioMesh "
+                f"backend {backend!r} cannot consume a GridMesh "
                 f"(drop mesh= or pass backend='jax'/'auto')")
         # Per-scenario availability (refined plans) IS shardable: the
         # (S, R, L) self-owned stacks shard over "data" alongside the
@@ -221,8 +221,7 @@ def _prepare_stream(jobs, policies, scenarios, r_total, windows, selfowned,
     return source, gplan, backend, chunk, single, mesh, overlap
 
 
-def _dispatch(backend, gplan, batch, early_start, out, interpret,
-              mesh=None) -> None:
+def _dispatch(backend, gplan, batch, early_start, out, mesh=None) -> None:
     if backend == "numpy":
         from repro.engine import backend_numpy
         backend_numpy.run(gplan, batch, early_start, out)
@@ -231,8 +230,7 @@ def _dispatch(backend, gplan, batch, early_start, out, interpret,
         backend_jax.run(gplan, batch, early_start, out, mesh=mesh)
     else:
         from repro.engine import backend_pallas
-        backend_pallas.run(gplan, batch, early_start, out,
-                           interpret=interpret)
+        backend_pallas.run(gplan, batch, early_start, out)
 
 
 def _prefetched(stream):
@@ -248,6 +246,33 @@ def _prefetched(stream):
         prev = item
     if prev is not None:
         yield prev
+
+
+def _chunk_loop(source, gplan, backend, chunk, mesh, overlap, early_start,
+                out_for):
+    """The one scenario-chunk loop of every entry point.
+
+    Synthesizes each chunk of ``source`` and evaluates it into
+    ``out_for(s0, s1)``, the per-key dict of (s1 - s0, J, P) arrays the
+    backend writes into: the caller decides whether that is a slice of
+    its full tensor, a reused buffer or a fresh chunk. Yields
+    ``(s0, s1, out, synth_seconds, eval_seconds)`` after each chunk's
+    span closes, so the consumer's work between chunks (folds, adaptive
+    feedback) runs before the next chunk is built."""
+    J, P = gplan.n_jobs, gplan.n_policies
+    rows = len(gplan.groups) * J
+    stream = source.chunks(chunk, device=(backend != "numpy"), mesh=mesh)
+    if overlap:
+        stream = _prefetched(stream)
+    for ci, (s0, s1, batch) in enumerate(stream):
+        with span("chunk", index=ci, s0=s0, s1=s1, backend=backend):
+            with span("synth", s0=s0, s1=s1, overlap=overlap) as sp_s:
+                batch.prepare()
+            out = out_for(s0, s1)
+            with span("eval", s0=s0, s1=s1, backend=backend, rows=rows,
+                      cells=(s1 - s0) * J * P) as sp_e:
+                _dispatch(backend, gplan, batch, early_start, out, mesh)
+        yield s0, s1, out, sp_s.seconds, sp_e.seconds
 
 
 @dataclasses.dataclass
@@ -283,7 +308,6 @@ def evaluate_grid_chunks(
     availability: Callable | Sequence[Callable] | None = None,
     backend: str = "auto",
     plan_backend: str = "auto",
-    interpret: bool | None = None,
     mesh=None,
     overlap: bool | None = None,
 ) -> Iterator[GridChunk]:
@@ -314,22 +338,12 @@ def evaluate_grid_chunks(
 
     def _iter():
         J, P = gplan.n_jobs, gplan.n_policies
-        rows = len(gplan.groups) * J
         wl = np.maximum(gplan.workload, 1e-12)
-        stream = source.chunks(chunk, device=(backend != "numpy"),
-                               mesh=mesh)
-        if overlap:
-            stream = _prefetched(stream)
-        for ci, (s0, s1, batch) in enumerate(stream):
-            with span("chunk", index=ci, s0=s0, s1=s1, backend=backend):
-                with span("synth", s0=s0, s1=s1, overlap=overlap) as sp_s:
-                    batch.prepare()
-                out = {k: np.zeros((s1 - s0, J, P)) for k in _OUT_KEYS}
-                with span("eval", s0=s0, s1=s1, backend=backend, rows=rows,
-                          cells=(s1 - s0) * J * P) as sp_e:
-                    _dispatch(backend, gplan, batch, early_start, out,
-                              interpret, mesh)
-            synth_t, eval_t = sp_s.seconds, sp_e.seconds
+        fresh = lambda s0, s1: {k: np.zeros((s1 - s0, J, P))
+                                for k in _OUT_KEYS}
+        for s0, s1, out, synth_t, eval_t in _chunk_loop(
+                source, gplan, backend, chunk, mesh, overlap, early_start,
+                fresh):
             unit = (out["spot_cost"] + out["ondemand_cost"]) \
                 / wl[None, :, None]
             yield GridChunk(s0=s0, s1=s1, unit_cost=unit, out=out,
@@ -353,7 +367,6 @@ def evaluate_grid(
     availability: Callable | Sequence[Callable] | None = None,
     backend: str = "auto",
     plan_backend: str = "auto",
-    interpret: bool | None = None,
     scenario_chunk: int | None = None,
     reduce: str = "stack",
     mesh=None,
@@ -386,11 +399,10 @@ def evaluate_grid(
     allocation per policy (fixed-policy sweep semantics of ``run_jobs``).
     ``plan_backend`` selects where the plan tensors are built (see
     :func:`resolve_plan_backend`); ``timings["plan_device"]`` reports the
-    device-build seconds (0.0 on the host plan path). ``interpret``
-    forces/forbids pallas interpret mode (default: interpret off-TPU).
+    device-build seconds (0.0 on the host plan path).
 
     ``mesh`` shards the SCENARIO axis across a device mesh (DESIGN.md §9):
-    pass a ``ScenarioMesh``, an int shard count (at most the visible
+    pass a ``GridMesh``, an int shard count (at most the visible
     devices), or a jax ``Mesh`` with a ``"data"`` axis.
     Mesh evaluation is a jax-backend feature ("auto" resolves to jax;
     numpy/pallas raise) — each shard synthesizes and scores only its own
@@ -419,38 +431,22 @@ def evaluate_grid(
                     pool, availability, backend, plan_backend,
                     scenario_chunk, mesh, overlap)
         S, J, P = source.n_scenarios, gplan.n_jobs, gplan.n_policies
-        rows = len(gplan.groups) * J
         root.set(backend=backend, scenarios=S, overlap=overlap)
 
         if reduce == "stack":
+            # The backend writes straight into slices of the (S, J, P)
+            # tensors: no chunk tensor, no copy.
             out = {k: np.zeros((S, J, P)) for k in _OUT_KEYS}
+            out_for = lambda s0, s1: {k: v[s0:s1] for k, v in out.items()}
         else:
             acc = {k: np.zeros((J, P)) for k in _OUT_KEYS}
             buf = {k: np.zeros((chunk, J, P)) for k in _OUT_KEYS}
+            out_for = lambda s0, s1: {k: v[:s1 - s0] for k, v in buf.items()}
         chunk_timings: list[dict] = []
         synth_total = eval_total = 0.0
-        # Mirrors evaluate_grid_chunks' loop ON PURPOSE: the stack path
-        # writes backend output straight into the (S, J, P) slices —
-        # layering on GridChunk would pay a full extra tensor copy per
-        # chunk.
-        stream = source.chunks(chunk, device=(backend != "numpy"),
-                               mesh=mesh)
-        if overlap:
-            stream = _prefetched(stream)
-        for ci, (s0, s1, batch) in enumerate(stream):
-            with span("chunk", index=ci, s0=s0, s1=s1, backend=backend):
-                with span("synth", s0=s0, s1=s1, overlap=overlap) as sp_s:
-                    batch.prepare()
-                synth_t = sp_s.seconds
-                if reduce == "stack":
-                    out_chunk = {k: v[s0:s1] for k, v in out.items()}
-                else:
-                    out_chunk = {k: v[:s1 - s0] for k, v in buf.items()}
-                with span("eval", s0=s0, s1=s1, backend=backend, rows=rows,
-                          cells=(s1 - s0) * J * P) as sp_e:
-                    _dispatch(backend, gplan, batch, early_start, out_chunk,
-                              interpret, mesh)
-                eval_t = sp_e.seconds
+        for s0, s1, out_chunk, synth_t, eval_t in _chunk_loop(
+                source, gplan, backend, chunk, mesh, overlap, early_start,
+                out_for):
             if reduce == "mean":
                 for k in _OUT_KEYS:
                     acc[k] += out_chunk[k].sum(axis=0)

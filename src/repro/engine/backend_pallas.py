@@ -8,8 +8,8 @@ the widest bid), and the chain recurrence runs inside the kernel. Planned-
 start grids (early_start=False) use the original per-task ``policy_cost``
 kernel on the flattened task batch.
 
-Off-TPU the kernels run in interpret mode (slow, parity-testing only);
-``interpret`` can be forced either way. Each launch, wrapper layout work
+On the CPU the kernels run in interpret mode (slow, parity-testing only;
+``repro.kernels.default_interpret``). Each launch, wrapper layout work
 included, is one jitted program announced to ``repro.obs`` as
 ``engine.eval.pallas_chain`` / ``engine.eval.pallas_task``; on a TPU its
 compiled text holds the Mosaic kernel as a ``tpu_custom_call``. Inside
@@ -43,16 +43,15 @@ def _kernels():
                 "slot", "p_od", "block_tasks", "interpret")))
 
 
-def run(gplan, batch, early_start: bool, out, interpret: bool | None = None,
-        block_rows: int = 128) -> None:
+def run(gplan, batch, early_start: bool, out, block_rows: int = 128) -> None:
     import jax
     import jax.numpy as jnp
 
+    from repro.kernels import default_interpret
     from repro.kernels.policy_cost import lookup_tiles
 
     chain, task = _kernels()
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = default_interpret()
     slot = batch.slot
     p_od = batch.p_ondemand
     J = gplan.n_jobs

@@ -269,7 +269,6 @@ def evaluate_grid_delta(prev, jobs, policies, scenarios, r_total: int = 0, *,
                         early_start: bool = True, pool: str = "dedicated",
                         backend: str | None = None,
                         plan_backend: str | None = None,
-                        interpret: bool | None = None,
                         scenario_chunk: int | None = None,
                         mesh=None, overlap: bool | None = None):
     """Re-evaluate a policy grid incrementally against a previous result.
@@ -360,7 +359,7 @@ def evaluate_grid_delta(prev, jobs, policies, scenarios, r_total: int = 0, *,
         inner = evaluate_grid(
             jobs, rep_pols, scenarios, r_total, windows=windows,
             selfowned=selfowned, early_start=early_start, pool=pool,
-            backend=backend, plan_backend=plan_backend, interpret=interpret,
+            backend=backend, plan_backend=plan_backend,
             scenario_chunk=scenario_chunk, reduce="stack", mesh=mesh,
             overlap=overlap)
         for i, gi in enumerate(changed):

@@ -6,11 +6,9 @@ fold crosses scenarios — so sharding it is pure data parallelism along a
 mesh axis named ``"data"``.  The eval-group axis (bid x policy-group rows
 of the grid plan) is *also* independent per group, so grids whose group
 axis dwarfs S (exp1's 175-policy sweeps) shard it along a second mesh axis
-named ``"model"`` — the same data/model two-axis decomposition as
-``launch/mesh.py``'s production meshes.  Logical axes ``scenario ->
-"data"`` and ``group -> "model"`` are routed through the
-``distributed/sharding.py`` rule table; a 1-wide ``"model"`` axis
-reproduces the 1-D behavior bitwise.
+named ``"model"``.  ``GridMesh`` maps the logical axes ``scenario ->
+"data"``, ``group -> "model"`` and ``bid -> None`` itself; a 1-wide
+``"model"`` axis reproduces the 1-D behavior bitwise.
 
 ``GridMesh`` is hashable (it keys the backends' compiled-program caches)
 and owns the padding contract for BOTH axes:
@@ -38,11 +36,10 @@ from typing import Any
 
 import numpy as np
 
-__all__ = [
-    "GridMesh", "ScenarioMesh", "as_scenario_mesh", "pad_to", "edge_repeat",
-]
+__all__ = ["GridMesh", "as_scenario_mesh", "pad_to", "edge_repeat"]
 
-_OVERRIDES = {"scenario": "data", "group": "model", "bid": None}
+# Logical axis -> mesh axis (None: replicated).
+_LOGICAL_AXES = {"scenario": "data", "group": "model", "bid": None}
 
 
 def pad_to(k: int, n: int) -> int:
@@ -68,7 +65,7 @@ def edge_repeat(a: np.ndarray, rows: int) -> np.ndarray:
 
 @dataclasses.dataclass(frozen=True)
 class GridMesh:
-    """A 2-D ``("data", "model")`` mesh plus its logical-axis rule table.
+    """A 2-D ``("data", "model")`` mesh plus its logical-axis map.
 
     Frozen and hashable — ``backend_jax`` and the learn-fold cache one
     compiled ``shard_map`` program per (mesh, shape) key.  A 1-D raw
@@ -77,7 +74,6 @@ class GridMesh:
     """
 
     mesh: Any                 # jax.sharding.Mesh (hashable)
-    rules: Any                # distributed.sharding.ShardingRules
 
     @classmethod
     def create(cls, n_devices: int | None = None,
@@ -90,9 +86,6 @@ class GridMesh:
         visible raises ``ValueError`` naming both counts.
         """
         import jax
-
-        from repro.distributed.sharding import ShardingRules
-        from repro.launch.mesh import make_mesh
 
         avail = len(jax.devices())
         m = int(model_devices)
@@ -110,9 +103,7 @@ class GridMesh:
                 f"N host devices)")
         shape, axes = ((n, m), ("data", "model")) if m > 1 else \
             ((n,), ("data",))
-        mesh = make_mesh(shape, axes)
-        rules = ShardingRules.create(mesh, overrides=_OVERRIDES)
-        return cls(mesh=mesh, rules=rules)
+        return cls(mesh=jax.make_mesh(shape, axes))
 
     @property
     def n_shards(self) -> int:
@@ -138,9 +129,15 @@ class GridMesh:
         return pad_to(g, self.model_shards)
 
     def spec(self, *logical_axes: str | None):
-        """PartitionSpec through the rule table (``"scenario" -> "data"``,
-        ``"group" -> "model"``)."""
-        return self.rules.spec(*logical_axes)
+        """PartitionSpec through the logical-axis map (``"scenario" ->
+        "data"``, ``"group" -> "model"``); a mesh axis this mesh lacks
+        maps to None (replicated)."""
+        from jax.sharding import PartitionSpec
+
+        names = self.mesh.axis_names
+        return PartitionSpec(*(
+            ax if ax in names else None
+            for ax in (_LOGICAL_AXES.get(a) for a in logical_axes)))
 
     def sharding(self, *logical_axes: str | None):
         """NamedSharding placing the named logical axes on this mesh."""
@@ -162,15 +159,10 @@ class GridMesh:
                               self.sharding("scenario"))
 
 
-# PR 6 name; every ``mesh=`` call site accepts both.  The 1-D scenario
-# mesh IS a GridMesh with a 1-wide (absent) "model" axis.
-ScenarioMesh = GridMesh
-
-
 def as_scenario_mesh(mesh) -> GridMesh | None:
     """Normalize every accepted ``mesh=`` argument.
 
-    Accepts ``None`` (unsharded), a ``GridMesh``/``ScenarioMesh``, an int
+    Accepts ``None`` (unsharded), a ``GridMesh``, an int
     (scenario-shard count, at most the visible devices), or a raw jax
     ``Mesh`` whose axes include ``"data"`` (a ``"model"`` axis, when
     present, shards the eval-group axis).
@@ -193,10 +185,7 @@ def as_scenario_mesh(mesh) -> GridMesh | None:
             raise ValueError(
                 f"scenario mesh needs a 'data' axis (got axes "
                 f"{tuple(mesh.axis_names)}); build one with "
-                f"GridMesh.create(n) or make_mesh((n,), ('data',))")
-        from repro.distributed.sharding import ShardingRules
-
-        rules = ShardingRules.create(mesh, overrides=_OVERRIDES)
-        return GridMesh(mesh=mesh, rules=rules)
+                f"GridMesh.create(n) or jax.make_mesh((n,), ('data',))")
+        return GridMesh(mesh=mesh)
     raise ValueError(f"mesh must be None, an int shard count, a "
                      f"GridMesh, or a jax Mesh (got {type(mesh)})")

@@ -19,4 +19,12 @@ algorithm) and a jit'd wrapper in ops.py; validated in interpret mode on CPU.
 
 from repro.kernels.ops import flash_attention, policy_cost_batch, ssd
 
-__all__ = ["flash_attention", "ssd", "policy_cost_batch"]
+__all__ = ["flash_attention", "ssd", "policy_cost_batch", "default_interpret"]
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode for the engine's and the learner's kernel
+    launches: on exactly when JAX runs on the CPU."""
+    import jax
+
+    return jax.default_backend() == "cpu"

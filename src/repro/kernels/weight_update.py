@@ -45,6 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
 from repro.kernels.policy_cost import to_col, to_row
 
 __all__ = ["hedge_replay"]
@@ -185,7 +186,7 @@ def hedge_replay(C, etas, u, n_done, *, block_jobs: int = 128,
     ``weights`` (S, K, P). ``block_jobs`` is rounded up to 128 lanes.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
     C = np.asarray(C, dtype=np.float32)
     S, J, P = C.shape
     etas = np.atleast_2d(np.asarray(etas, dtype=np.float32))

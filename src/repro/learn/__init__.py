@@ -16,8 +16,8 @@ into realized/expected regret curves with per-scenario confidence bands.
     lr = replay(res, arrivals, d, learners=["hedge", "exp3"], backend="jax")
     lr.regret_curve()     # (S, K, J) running regret per learner
 
-``repro.core.tola.run_tola`` delegates its Alg. 4 loop to the numpy oracle
-here, bit-compatibly with the pre-subsystem implementation.
+``repro.core.tola.run_tola_scenarios`` delegates its Alg. 4 loop to the
+numpy oracle here, bit-compatibly with the pre-subsystem implementation.
 """
 
 from repro.learn.learners import (

@@ -66,8 +66,8 @@ class Schedule:
     ``alg4``    — the paper's Alg. 4 line 16: at the update event of job j
                   (time ``t = a_j + d``), ``eta = sqrt(2 log m / (d *
                   max(t - d, d)))``; reproduced operation-for-operation so
-                  the numpy replay stays bit-compatible with the pre-learn
-                  ``run_tola`` loop.
+                  the numpy replay stays bit-compatible with the original
+                  in-module Alg. 4 loop.
     ``const``   — a constant ``c`` (the eta-grid axis of the sweeps).
     ``invsqrt`` — ``c / sqrt(j + 1)`` over the job index.
     """

@@ -10,7 +10,7 @@ replays ANY learner of ``learners.py`` over that tensor:
 
 * ``backend="numpy"`` — the sequential float64 event loop, the exact
   oracle. For ``hedge`` with the ``alg4`` schedule it is bit-compatible
-  with the pre-subsystem ``run_tola`` loop (same logw arithmetic, same
+  with the original in-module Alg. 4 loop (same logw arithmetic, same
   uniform-stream consumption as ``rng.choice`` — see ``_sample_cdf``).
 * ``backend="jax"``  — the same event stream as ONE ``jax.lax.scan``,
   compiled once per learner kind and vmapped across scenarios x (learner,
@@ -350,7 +350,6 @@ def replay(
     seed: int = 0,
     rng: np.random.Generator | None = None,
     backend: str = "auto",
-    interpret: bool | None = None,
 ) -> LearnResult:
     """Replay a batch of learners over a (S, J, P) counterfactual tensor.
 
@@ -361,8 +360,9 @@ def replay(
     (defaults to 1). ``learners`` is a flat list of kinds / ``LearnerSpec``s
     — a schedule grid is expressed as more specs; the result keeps their
     order. ``rng`` (single-scenario only) draws the uniform stream from a
-    live generator — the hook ``run_tola`` uses to stay bit-compatible with
-    its legacy sampling stream; otherwise scenario s uses ``seed + s``.
+    live generator — the hook ``run_tola_scenarios`` uses to stay
+    bit-compatible with its legacy sampling stream; otherwise scenario s
+    uses ``seed + s``.
     """
     if hasattr(C, "unit_cost"):
         if workload is None:
@@ -427,8 +427,7 @@ def replay(
                              if sp.kind == "hedge"]
                 if pallas_ks:
                     from repro.kernels.weight_update import hedge_replay
-                    out = hedge_replay(C, etas[pallas_ks], u, n_done,
-                                       interpret=interpret)
+                    out = hedge_replay(C, etas[pallas_ks], u, n_done)
                     for i, k in enumerate(pallas_ks):
                         chosen[:, k] = out["chosen"][:, i]
                         p_sel[:, k] = out["p_chosen"][:, i]
@@ -469,7 +468,6 @@ def replay_stream(
     windows: str = "dealloc",
     selfowned: str = "prop12",
     early_start: bool = True,
-    interpret: bool | None = None,
     mesh=None,
     overlap: bool | None = None,
 ) -> StreamLearnResult:
@@ -533,7 +531,7 @@ def replay_stream(
         jobs, policies, source, r_total,
         scenario_chunk=scenario_chunk, windows=windows,
         selfowned=selfowned, early_start=early_start, pool="dedicated",
-        backend=engine_backend, interpret=interpret, mesh=mesh,
+        backend=engine_backend, mesh=mesh,
         overlap=overlap)
     if mesh is None:
         with span("replay_stream", backend=backend):
@@ -541,7 +539,7 @@ def replay_stream(
                 with span("fold", chunk=ci, s0=ch.s0, s1=ch.s1):
                     lr = replay(ch.unit_cost, arrivals, d, workload=Z,
                                 learners=specs, seed=seed + ch.s0,
-                                backend=backend, interpret=interpret)
+                                backend=backend)
                     feedback = acc.fold(lr)
                 _weight_metrics(specs, lr.weights.mean(axis=0))
                 # The chunk-boundary round trip: a no-op for every

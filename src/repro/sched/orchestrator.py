@@ -25,7 +25,7 @@ import numpy as np
 from repro.core import (
     Policy,
     SpotMarket,
-    run_tola,
+    run_tola_scenarios,
     selfowned_policies,
     spot_od_policies,
     transform,
@@ -60,7 +60,8 @@ class FleetOrchestrator:
         r = self.fleet.reserved_pods
         grid = selfowned_policies() if r > 0 else spot_od_policies()
         if learn:
-            res = run_tola(chains, grid, self.market, r_total=r, seed=seed)
+            res = run_tola_scenarios(chains, grid, [self.market],
+                                     r_total=r, seed=seed)[0]
             costs = res.realized
             best = grid[int(np.argmax(res.weights))]
             top_w = float(res.weights.max())

@@ -14,7 +14,7 @@ from repro.core import (
     spot_od_policies,
 )
 from repro.core.scheduler import evaluate_policy_fullpool
-from repro.core.tola import cost_matrix, run_tola, run_tola_scenarios
+from repro.core.tola import run_tola_scenarios
 from repro.engine import (
     available_backends,
     evaluate_grid,
@@ -42,8 +42,7 @@ def test_backend_parity_randomized_streams(backend, seed):
     """numpy vs jax vs pallas(interpret) agree on the cost matrix to 1e-5."""
     jobs, m = _setup(seed=seed, mseed=seed + 10)
     ref = evaluate_grid(jobs, _grid(), m, r_total=60, backend="numpy")
-    got = evaluate_grid(jobs, _grid(), m, r_total=60, backend=backend,
-                        interpret=True if backend == "pallas" else None)
+    got = evaluate_grid(jobs, _grid(), m, r_total=60, backend=backend)
     np.testing.assert_allclose(got.matrix, ref.matrix, atol=TOL, rtol=TOL)
 
 
@@ -111,7 +110,8 @@ def test_shared_pool_matches_run_jobs():
 def test_cost_matrix_routes_through_engine():
     jobs, m = _setup()
     pols = _grid()
-    C = cost_matrix(jobs, pols, m, r_total=30)
+    C = run_tola_scenarios(jobs, pols, [m], r_total=30, pool_iters=0,
+                           backend="numpy")[0].cost_matrix
     res = evaluate_grid(jobs, pols, m, r_total=30, backend="numpy")
     np.testing.assert_array_equal(C, res.matrix)
     assert C.shape == (len(jobs), len(pols))
@@ -147,7 +147,8 @@ def test_run_tola_scenarios_batches():
     markets = make_scenarios(m.horizon, 2, seed=33)
     batch = run_tola_scenarios(jobs, pols, markets, seed=3,
                                backend="numpy")
-    solo = run_tola(jobs, pols, markets[0], seed=3, backend="numpy")
+    solo = run_tola_scenarios(jobs, pols, [markets[0]], seed=3,
+                              backend="numpy")[0]
     assert len(batch) == 2
     np.testing.assert_array_equal(batch[0].cost_matrix, solo.cost_matrix)
     np.testing.assert_array_equal(batch[0].chosen, solo.chosen)

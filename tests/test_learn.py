@@ -1,5 +1,5 @@
 """Online-learning subsystem: numpy-vs-scan-vs-pallas replay parity, Hedge
-bit-compatibility with the legacy run_tola loop, seed determinism of the
+bit-compatibility with the legacy in-module TOLA loop, seed determinism of the
 sampled trace across backends, weight-underflow robustness on long
 horizons, the Prop. B.1 regret-bound scaling, and the adversarial scenario
 family."""
@@ -11,7 +11,7 @@ from repro.core import (
     Policy,
     SpotMarket,
     generate_chain_jobs,
-    run_tola,
+    run_tola_scenarios,
     spot_od_policies,
 )
 from repro.learn import (
@@ -141,17 +141,19 @@ def test_seed_determinism_across_backends():
 
 
 def test_hedge_bit_compatible_with_legacy_loop():
-    """run_tola delegates to repro.learn and must reproduce the ORIGINAL
-    in-module event loop draw for draw (rng.choice consumption included)."""
+    """run_tola_scenarios delegates to repro.learn and must reproduce the
+    ORIGINAL in-module event loop draw for draw (rng.choice consumption
+    included)."""
     jobs = generate_chain_jobs(60, job_type=2, seed=3)
     market = SpotMarket(max(j.deadline for j in jobs) + 1, seed=4)
     grid = spot_od_policies()[:8]
-    res = run_tola(jobs, grid, market, seed=7, backend="numpy")
+    res = run_tola_scenarios(jobs, grid, [market], seed=7,
+                             backend="numpy")[0]
 
     # The pre-subsystem Algorithm 4 loop, verbatim.
-    from repro.core.tola import cost_matrix
+    from repro.engine import evaluate_grid
 
-    C = cost_matrix(jobs, grid, market, backend="numpy")
+    C = evaluate_grid(jobs, grid, market, backend="numpy").matrix
     arrivals = np.array([j.arrival for j in jobs])
     n, m = C.shape
     d = max(j.deadline - j.arrival for j in jobs)
@@ -292,13 +294,13 @@ def test_adversarial_scenarios_share_grid_and_bite():
 
 
 def test_run_tola_bandit_learner():
-    """run_tola accepts any learner kind; the realized bandit-TOLA stream
+    """run_tola_scenarios accepts any learner kind; the realized bandit-TOLA stream
     stays within the on-demand unit-cost ceiling and carries its replay."""
     jobs = generate_chain_jobs(150, job_type=2, seed=11)
     market = SpotMarket(max(j.deadline for j in jobs) + 1, seed=12)
     grid = spot_od_policies()[:10]
-    res = run_tola(jobs, grid, market, seed=0, backend="numpy",
-                   learner="exp3")
+    res = run_tola_scenarios(jobs, grid, [market], seed=0, backend="numpy",
+                             learner="exp3")[0]
     assert res.learn is not None and res.learn.specs[0].kind == "exp3"
     assert 0.0 < res.average_unit_cost() <= market.p_ondemand + 1e-9
     # the counterfactual replay regret is consistent with the cost matrix

@@ -472,12 +472,12 @@ def test_capture_never_raises_on_bad_program():
 
 def test_streamed_observation_end_to_end(tmp_path):
     pytest.importorskip("jax")
-    from repro.engine import ScenarioMesh
+    from repro.engine import GridMesh
     from repro.learn import replay_stream
 
     jobs, horizon = _setup()
     spec = ScenarioSpec("fresh", horizon, 4, seed=9)
-    mesh = ScenarioMesh.create(1)
+    mesh = GridMesh.create(1)
     with obs.observe(programs=True) as session:
         out = replay_stream(jobs, GRID[:4], spec, scenario_chunk=2,
                             backend="jax", engine_backend="jax", mesh=mesh)
@@ -666,12 +666,12 @@ def test_tola_span_tree():
 
 
 def test_single_market_tola_has_one_root():
-    from repro.core import run_tola
+    from repro.core import run_tola_scenarios
 
     jobs, markets = _tola_inputs()
     with obs.tracing() as tr:
-        run_tola(jobs, GRID, markets[0], r_total=4, pool_iters=1,
-                 backend="numpy")
+        run_tola_scenarios(jobs, GRID, markets[:1], r_total=4, pool_iters=1,
+                           backend="numpy")
     (root,) = tr.roots()
     assert root.name == "tola"
     assert len(tr.named("tola.round")) == 2

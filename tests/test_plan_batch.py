@@ -18,7 +18,7 @@ from repro.core.dealloc import (
     window_sizes_batch_jax,
 )
 from repro.core.scheduler import build_plans, build_plans_batch, job_arrays
-from repro.core.tola import run_tola, run_tola_scenarios
+from repro.core.tola import run_tola_scenarios
 from repro.engine import make_scenarios
 from repro.engine.plan import distinct_window_params
 
@@ -166,8 +166,8 @@ def test_run_tola_scenarios_one_engine_pass_per_round(monkeypatch):
                for a in calls[1:])
 
     for s, m in enumerate(markets):
-        solo = run_tola(jobs, pols, m, r_total=50, seed=7 + s,
-                        pool_iters=pool_iters, backend="numpy")
+        solo = run_tola_scenarios(jobs, pols, [m], r_total=50, seed=7 + s,
+                                  pool_iters=pool_iters, backend="numpy")[0]
         np.testing.assert_array_equal(batch[s].cost_matrix, solo.cost_matrix)
         np.testing.assert_array_equal(batch[s].chosen, solo.chosen)
         np.testing.assert_array_equal(batch[s].weights, solo.weights)
@@ -218,9 +218,7 @@ def test_per_scenario_availability_backend_parity(backend, early_start):
     if not early_start:
         kw.update(windows="even", selfowned="naive")
     ref = evaluate_grid(jobs, pols, markets, 50, backend="numpy", **kw)
-    got = evaluate_grid(jobs, pols, markets, 50, backend=backend,
-                        interpret=True if backend == "pallas" else None,
-                        **kw)
+    got = evaluate_grid(jobs, pols, markets, 50, backend=backend, **kw)
     np.testing.assert_allclose(got.unit_cost, ref.unit_cost,
                                atol=1e-5, rtol=1e-5)
 
@@ -460,7 +458,7 @@ def test_device_plan_pallas_backend_parity():
     pols = selfowned_policies()[::60]
     ref = evaluate_grid(jobs, pols, markets, 40, backend="numpy")
     dev = evaluate_grid(jobs, pols, markets, 40, backend="pallas",
-                        plan_backend="device", interpret=True)
+                        plan_backend="device")
     np.testing.assert_allclose(dev.unit_cost, ref.unit_cost,
                                atol=1e-5, rtol=1e-5)
 

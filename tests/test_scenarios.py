@@ -138,8 +138,7 @@ def test_spec_device_parity_all_backends(backend, kind):
     spec = ScenarioSpec(kind, horizon, 4, seed=13)
     ref = evaluate_grid(jobs, _grid(6), spec, 20, backend="numpy")
     got = evaluate_grid(jobs, _grid(6), spec, 20, backend=backend,
-                        scenario_chunk=2,
-                        interpret=True if backend == "pallas" else None)
+                        scenario_chunk=2)
     np.testing.assert_allclose(got.unit_cost, ref.unit_cost,
                                atol=TOL, rtol=TOL)
 

@@ -12,7 +12,7 @@ from repro.core import (
     generate_chain_jobs,
     run_greedy,
     run_jobs,
-    run_tola,
+    run_tola_scenarios,
     spot_od_policies,
 )
 from repro.core.pool import RangeMax
@@ -80,7 +80,7 @@ def test_tola_learns_good_policy():
     fixed cost is near the best fixed cost."""
     jobs, m = _setup(400, jt=2, seed=11)
     grid = spot_od_policies()
-    res = run_tola(jobs, grid, m, seed=0)
+    res = run_tola_scenarios(jobs, grid, [m], seed=0)[0]
     fixed = res.fixed_unit_costs
     # weight-weighted expected cost is better than the uniform average
     uniform = fixed.mean()

@@ -24,7 +24,6 @@ import pytest
 from repro.core import generate_chain_jobs, selfowned_policies
 from repro.engine import (
     GridMesh,
-    ScenarioMesh,
     ScenarioSpec,
     as_scenario_mesh,
     evaluate_grid,
@@ -49,7 +48,7 @@ GRID = selfowned_policies()[:12]
 # --------------------------------------------------------------------------
 
 def test_mesh_create_defaults_and_padding():
-    mesh = ScenarioMesh.create()
+    mesh = GridMesh.create()
     assert mesh.n_shards == len(jax.devices())
     n = mesh.n_shards
     assert mesh.pad(0) == 0
@@ -64,12 +63,13 @@ def test_mesh_create_defaults_and_padding():
 
 
 def test_mesh_2d_axes_and_group_padding():
-    # GridMesh generalizes ScenarioMesh (same class): a second logical
-    # axis group -> "model" with its own whole-group padding contract.
-    assert GridMesh is ScenarioMesh
+    # The 1-D mesh is a GridMesh whose second logical axis
+    # group -> "model" is absent, with its own whole-group padding contract.
     mesh = GridMesh.create(1)          # 1-D: model axis absent, 1-wide
     assert mesh.data_shards == 1
     assert mesh.model_shards == 1
+    from jax.sharding import PartitionSpec as P
+    assert mesh.spec("scenario", "group", "bid") == P("data", None, None)
     assert mesh.pad_groups(5) == 5
     from repro.engine.mesh import edge_repeat, pad_to
     assert pad_to(13, 4) == 16 and pad_to(8, 4) == 8 and pad_to(0, 3) == 0
@@ -106,7 +106,7 @@ def test_mesh_2d_create(shape):
 def test_mesh_create_raises_past_visible_devices_1d():
     avail = len(jax.devices())
     with pytest.raises(ValueError) as err:
-        ScenarioMesh.create(avail + 7)
+        GridMesh.create(avail + 7)
     msg = str(err.value)
     # the message names both the requested and the visible device counts
     assert f"{avail + 7}-device" in msg and f"only {avail} device" in msg
@@ -124,7 +124,7 @@ def test_mesh_create_raises_past_visible_devices_2d():
 
 def test_as_scenario_mesh_normalization():
     assert as_scenario_mesh(None) is None
-    mesh = ScenarioMesh.create(1)
+    mesh = GridMesh.create(1)
     assert as_scenario_mesh(mesh) is mesh
     assert as_scenario_mesh(1).n_shards == 1
     with pytest.raises(ValueError):
@@ -141,8 +141,8 @@ def test_as_scenario_mesh_normalization():
 
 
 def test_mesh_is_hashable_cache_key():
-    m1 = ScenarioMesh.create(1)
-    m2 = ScenarioMesh.create(1)
+    m1 = GridMesh.create(1)
+    m2 = GridMesh.create(1)
     assert hash(m1) == hash(m2)
     assert m1 == m2
 
@@ -154,7 +154,7 @@ def test_mesh_is_hashable_cache_key():
 def test_mesh_rejects_non_jax_backends():
     jobs, horizon = _setup()
     spec = ScenarioSpec("fresh", horizon, 4, seed=3)
-    mesh = ScenarioMesh.create(1)
+    mesh = GridMesh.create(1)
     with pytest.raises(ValueError, match="mesh"):
         evaluate_grid(jobs, GRID, spec, 300, backend="numpy", mesh=mesh)
     with pytest.raises(ValueError, match="mesh"):
@@ -184,7 +184,7 @@ def test_mesh_shards_per_scenario_availability():
                         availability=avail).unit_cost
     got = evaluate_grid(jobs, GRID, markets, 300, backend="jax",
                         availability=avail,
-                        mesh=ScenarioMesh.create(1)).unit_cost
+                        mesh=GridMesh.create(1)).unit_cost
     assert np.array_equal(ref, got)
     assert np.abs(got - oracle).max() < 1e-5
 
@@ -204,7 +204,7 @@ def test_replay_stream_mesh_rejects_numpy_replay():
     spec = ScenarioSpec("fresh", horizon, 4, seed=3)
     with pytest.raises(ValueError, match="mesh"):
         replay_stream(jobs, GRID, spec, 300, backend="numpy",
-                      mesh=ScenarioMesh.create(1))
+                      mesh=GridMesh.create(1))
 
 
 # --------------------------------------------------------------------------
@@ -217,7 +217,7 @@ def test_one_device_mesh_bitwise_spec(kind):
     spec = ScenarioSpec(kind, horizon, 5, seed=7)
     ref = evaluate_grid(jobs, GRID, spec, 300, backend="jax")
     got = evaluate_grid(jobs, GRID, spec, 300, backend="jax",
-                        mesh=ScenarioMesh.create(1))
+                        mesh=GridMesh.create(1))
     assert np.array_equal(ref.unit_cost, got.unit_cost)
     assert np.array_equal(ref.spot_cost, got.spot_cost)
 
@@ -227,7 +227,7 @@ def test_one_device_mesh_bitwise_market_list():
     markets = make_scenarios(horizon, 3, seed=1)
     ref = evaluate_grid(jobs, GRID, markets, 300, backend="jax")
     got = evaluate_grid(jobs, GRID, markets, 300, backend="jax",
-                        mesh=ScenarioMesh.create(1))
+                        mesh=GridMesh.create(1))
     assert np.array_equal(ref.unit_cost, got.unit_cost)
 
 
@@ -237,7 +237,7 @@ def test_one_device_mesh_bitwise_task_path():
     ref = evaluate_grid(jobs, GRID, spec, 300, backend="jax",
                         early_start=False)
     got = evaluate_grid(jobs, GRID, spec, 300, backend="jax",
-                        early_start=False, mesh=ScenarioMesh.create(1))
+                        early_start=False, mesh=GridMesh.create(1))
     assert np.array_equal(ref.unit_cost, got.unit_cost)
 
 
@@ -250,12 +250,12 @@ def test_mesh_chunked_uneven_mean_matches_oracle():
                            reduce="mean").unit_cost
     sharded = evaluate_grid(jobs, GRID, spec, 300, backend="jax",
                             scenario_chunk=3, reduce="mean",
-                            mesh=ScenarioMesh.create(1)).unit_cost
+                            mesh=GridMesh.create(1)).unit_cost
     assert np.abs(sharded - oracle).max() < 1e-5
     # and without reduce: concatenated chunks, padding sliced off
     full = evaluate_grid(jobs, GRID, spec, 300, backend="jax",
                          scenario_chunk=3,
-                         mesh=ScenarioMesh.create(1)).unit_cost
+                         mesh=GridMesh.create(1)).unit_cost
     assert full.shape[0] == 7
     mono = evaluate_grid(jobs, GRID, spec, 300, backend="jax").unit_cost
     assert np.array_equal(full, mono)
@@ -289,7 +289,7 @@ def test_replay_stream_sharded_fold_matches_host_fold():
                         engine_backend="jax")
     sh = replay_stream(jobs, GRID, spec, 300, learners=learners, seed=11,
                        scenario_chunk=3, backend="jax",
-                       engine_backend="jax", mesh=ScenarioMesh.create(1))
+                       engine_backend="jax", mesh=GridMesh.create(1))
     assert sh.n_scenarios == ref.n_scenarios == 7
     assert sh.n_chunks == ref.n_chunks == 3
     # device f32 fold vs host f64-on-f32-traces fold: ~1e-4 budget
@@ -317,7 +317,7 @@ def test_replay_stream_sharded_adaptive_round_trip():
                         engine_backend="jax")
     sh = replay_stream(jobs, GRID, spec, 300, learners=["hedge"], seed=3,
                        scenario_chunk=4, backend="jax",
-                       engine_backend="jax", mesh=ScenarioMesh.create(1))
+                       engine_backend="jax", mesh=GridMesh.create(1))
     # the adversary consumed the SAME feedback signal chunk by chunk
     assert np.abs(ref.regret_per_job() - sh.regret_per_job()).max() < 1e-4
 
@@ -333,7 +333,7 @@ def test_run_tola_scenarios_accepts_mesh():
     # refinement rounds alike (DESIGN.md §9); 1-device mesh is bitwise
     got = run_tola_scenarios(jobs, GRID, markets, r_total=300, seed=0,
                              pool_iters=2, backend="jax",
-                             mesh=ScenarioMesh.create(1))
+                             mesh=GridMesh.create(1))
     for a, b in zip(ref, got):
         assert np.array_equal(a.cost_matrix, b.cost_matrix)
         assert np.array_equal(a.chosen, b.chosen)
@@ -356,7 +356,7 @@ def test_run_tola_scenarios_keeps_mesh_every_round(monkeypatch):
     monkeypatch.setattr(engine, "evaluate_grid", spy)
     jobs, horizon = _setup(n=12)
     markets = make_scenarios(horizon, 2, seed=1)
-    mesh = ScenarioMesh.create(1)
+    mesh = GridMesh.create(1)
     run_tola_scenarios(jobs, GRID, markets, r_total=300, seed=0,
                        pool_iters=2, backend="jax", mesh=mesh)
     assert [refined for refined, _ in seen] == [False, True, True]
@@ -370,7 +370,7 @@ def test_sweep_policies_accepts_mesh():
     spec = ScenarioSpec("fresh", horizon, 4, seed=2)
     _, a_ref, _, _ = sweep_policies(jobs, GRID, spec, 300, backend="jax")
     _, a_mesh, _, _ = sweep_policies(jobs, GRID, spec, 300, backend="jax",
-                                     mesh=ScenarioMesh.create(1))
+                                     mesh=GridMesh.create(1))
     assert a_ref == a_mesh
 
 
@@ -383,7 +383,7 @@ def test_sweep_policies_accepts_mesh():
 def _verify(keys):
     from repro.analysis.programs import verify_all
 
-    checks = verify_all(mesh=ScenarioMesh.create(), keys=keys)
+    checks = verify_all(mesh=GridMesh.create(), keys=keys)
     assert checks, f"no checks produced for {keys}"
     failed = [c for c in checks if not c.ok]
     assert not failed, "\n".join(f"{c.program}/{c.check}: {c.detail}"
@@ -445,7 +445,7 @@ def test_placement_violations_empty_on_contract():
     from repro.obs.compiled import placement_violations
 
     assert placement_violations(
-        mesh=ScenarioMesh.create(),
+        mesh=GridMesh.create(),
         keys=["engine.eval.chain:sharded", "learn.fold:sharded"]) == []
 
 
@@ -508,7 +508,7 @@ import numpy as np
 import jax
 from repro.core import generate_chain_jobs, selfowned_policies
 from repro.core import run_tola_scenarios
-from repro.engine import GridMesh, ScenarioMesh, ScenarioSpec, evaluate_grid
+from repro.engine import GridMesh, ScenarioSpec, evaluate_grid
 from repro.engine import make_scenarios
 from repro.learn import replay_stream
 
@@ -516,7 +516,7 @@ assert len(jax.devices()) == 8
 jobs = generate_chain_jobs(20, 2, seed=0)
 horizon = max(j.deadline for j in jobs) + 1.0
 grid = selfowned_policies()[:12]
-mesh = ScenarioMesh.create(8)
+mesh = GridMesh.create(8)
 out = {"n_shards": mesh.n_shards}
 
 # S=13 % 8 != 0 forces padding; parity vs the f64 oracle AND bitwise vs

@@ -22,7 +22,7 @@ import pytest
 
 import repro.engine
 from repro import obs
-from repro.core import Policy, run_tola, run_tola_scenarios
+from repro.core import Policy, run_tola_scenarios
 from repro.core.transform import transform
 from repro.core.types import DAGJob, Task
 from repro.engine import ScenarioSpec, evaluate_grid
@@ -132,16 +132,14 @@ def test_device_backends_match_reference_and_oracle(cell, oracle, backend,
 
 
 @pytest.mark.parametrize("pool_iters", [1, 2])
-@pytest.mark.parametrize("entry", ["run_tola", "run_tola_scenarios"])
+@pytest.mark.parametrize("entry", ["single_market", "all_markets"])
 def test_each_engine_call_has_its_round_span(cell, entry, pool_iters):
     jobs, pols = cell["jobs"][:12], cell["policies"][::25]
+    markets = cell["markets"][:1] if entry == "single_market" \
+        else cell["markets"]
     with obs.tracing() as tr:
-        if entry == "run_tola":
-            run_tola(jobs, pols, cell["markets"][0], 600,
-                     pool_iters=pool_iters, backend="numpy")
-        else:
-            run_tola_scenarios(jobs, pols, cell["markets"], 600,
-                               pool_iters=pool_iters, backend="numpy")
+        run_tola_scenarios(jobs, pols, markets, 600, pool_iters=pool_iters,
+                           backend="numpy")
     (root,) = tr.roots()
     (score,) = tr.named("tola.score")
     rescores = tr.named("tola.rescore")
@@ -186,10 +184,11 @@ def test_selfowned_share_gauge_is_the_realized_share(cell):
 
 def test_single_market_selfowned_share(cell):
     with METRICS.collecting(reset=True):
-        res = run_tola(cell["jobs"], cell["policies"], cell["markets"][0],
-                       600, seed=3, pool_iters=1, backend="numpy")
+        res = run_tola_scenarios(cell["jobs"], cell["policies"],
+                                 cell["markets"][:1], 600, seed=3,
+                                 pool_iters=1, backend="numpy")
     assert METRICS.gauge("tola.selfowned_share").value(round=1) \
-        == pytest.approx(_share([res]), rel=1e-12)
+        == pytest.approx(_share(res), rel=1e-12)
 
 
 def _akeys(policies, r_total):
